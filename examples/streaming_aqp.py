@@ -1,23 +1,16 @@
-"""Distributed streaming AQP: 8 edge shards, both transmission modes.
+"""Distributed streaming AQP: one edge shard per device, both transmission modes.
 
-Runs the sharded pipeline (shard_map over a data mesh) on the Chicago
-air-quality stream: each shard = one edge node sampling independently; the
-"cloud" estimate comes from either one psum of per-stratum moments
-(pre-agg mode) or an all-gather of compacted raw samples.  Prints the
-answers, their agreement, and the upstream byte cost of each mode — the
-paper's central bandwidth trade-off, measured.
+Runs the sharded pipeline (shard_map over a data mesh of every device JAX
+finds) on the Chicago air-quality stream: each shard = one edge node
+sampling independently; the "cloud" estimate comes from either one psum of
+per-stratum moments (pre-agg mode) or an all-gather of compacted raw
+samples.  Prints the answers, their agreement, and the upstream byte cost
+of each mode — the paper's central bandwidth trade-off, measured.
 
 Run:  PYTHONPATH=src python examples/streaming_aqp.py
-(relaunches itself with 8 host devices)
+On a CPU host, simulate 8 edge nodes by setting
+XLA_FLAGS=--xla_force_host_platform_device_count=8 before running.
 """
-
-import os
-import sys
-
-if os.environ.get("_REPRO_AQP_CHILD") != "1":
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.environ["_REPRO_AQP_CHILD"] = "1"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
 
 import jax
 import jax.numpy as jnp
@@ -27,14 +20,17 @@ from repro.core.pipeline import EdgeCloudPipeline, PipelineConfig
 from repro.data.streams import chicago_aq_stream
 from repro.sharding.compat import compat_make_mesh
 
+SHARD_TUPLES = 5_000  # tuples per edge node per window
+
 
 def main():
-    mesh = compat_make_mesh((8,), ("data",))
+    shards = len(jax.devices())
+    mesh = compat_make_mesh((shards,), ("data",))
     table = make_table(*CHICAGO_BBOX, precision=6, neighborhood_precision=4)
-    print(f"{len(jax.devices())} edge shards; {table.num_strata} strata")
+    print(f"{shards} edge shards; {table.num_strata} strata")
 
     stream = chicago_aq_stream(num_chunks=10, seed=1)
-    wnds = list(windows.count_windows(stream, window_size=40_000))
+    wnds = list(windows.count_windows(stream, window_size=SHARD_TUPLES * shards))
 
     pipes = {
         mode: EdgeCloudPipeline(

@@ -66,8 +66,6 @@ from . import codec as wirecodec
 from . import estimators, feedback, sampling
 from . import query as aqp
 
-from ..sharding.compat import compat_shard_map as _shard_map
-
 from .estimators import Estimate, StratumStats
 from .query import AggEstimate, AggSpec, Plan, Query, QueryResult
 from .sampling import SampleResult
@@ -756,7 +754,7 @@ class EdgeCloudPipeline:
             return jax.jit(body)
         axes = self.axis_names
         spec = P(axes)
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             partial(body, axes=axes),
             mesh=self.mesh,
             in_specs=(P(), spec, spec, {c: spec for c in plan.columns}, spec, P()),

@@ -38,6 +38,10 @@ import threading
 
 QUEUE_POLICIES = ("block", "drop-newest", "drop-oldest")
 
+
+class QueueClosed(RuntimeError):
+    """``put()`` on a closed queue: the consumer has stopped taking panes."""
+
 # canonical drop causes flowing through WindowBatch.drop_causes
 CAUSE_LATE = "late"  # bounded-buffer window overflow (windows.time_windows)
 CAUSE_QUEUE_FULL = "queue_full"  # backpressure policy drop at the ingest queue
@@ -106,6 +110,7 @@ class BoundedPaneQueue:
     def put(self, pane, timeout: float | None = None) -> bool:
         """Offer a pane; returns True iff *this* pane was admitted.
 
+        Raises :class:`QueueClosed` once :meth:`close` has been called.
         Under ``drop-oldest`` the arrival is admitted by evicting the head;
         under ``drop-newest`` a full queue sheds the arrival; under
         ``block`` the call waits for space (or ``timeout``).  Decimation
@@ -113,7 +118,7 @@ class BoundedPaneQueue:
         """
         with self._cond:
             if self._closed:
-                raise RuntimeError("put() on a closed BoundedPaneQueue")
+                raise QueueClosed("put() on a closed BoundedPaneQueue")
             self._arrivals += 1
             if self._decimate > 1 and (self._arrivals - 1) % self._decimate:
                 self._drop(pane, CAUSE_SHED)
@@ -130,7 +135,7 @@ class BoundedPaneQueue:
                         timeout=timeout,
                     )
                     if self._closed:
-                        raise RuntimeError("put() on a closed BoundedPaneQueue")
+                        raise QueueClosed("put() on a closed BoundedPaneQueue")
                     if not ok:
                         self._drop(pane, CAUSE_QUEUE_FULL)
                         return False

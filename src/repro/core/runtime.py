@@ -64,7 +64,7 @@ import numpy as np
 
 from . import feedback
 from .feedback import EventPolicy, EventState
-from .qdisc import BoundedPaneQueue, DropLedger
+from .qdisc import BoundedPaneQueue, DropLedger, QueueClosed
 from .windows import WindowBatch
 
 
@@ -276,6 +276,7 @@ class StreamRuntime:
         self._inflight: collections.deque[_InFlight] = collections.deque()
         self._staged: _Staged | None = None
         self._producer: threading.Thread | None = None
+        self._producer_error: Exception | None = None
         self._watches: dict[int, tuple] = {}  # qid -> (reg, policy, column, state)
         self._pending_scores: list = []  # (reg, lazy score, matured-at pane)
         self._prev_means: dict[int, object] = {}  # qid -> last pane's mean vector
@@ -383,8 +384,10 @@ class StreamRuntime:
                 t = clock()
                 self.queue.put(_Arrival(pane, t, t - t_prev))
                 t_prev = clock()
-        except RuntimeError:
-            return  # queue closed under us: consumer stopped early
+        except QueueClosed:
+            return  # the consumer stopped early and closed the queue
+        except Exception as e:  # the thread's boundary: run() re-raises it
+            self._producer_error = e
         finally:
             if not self.queue.closed:
                 self.queue.close()
@@ -393,7 +396,10 @@ class StreamRuntime:
 
     def run(self, source: Source, key=None, max_panes: int | None = None) -> list:
         """Drive the session over ``source`` with a producer thread; returns
-        the accumulated ``SessionStep`` history (also at ``self.history``)."""
+        the accumulated ``SessionStep`` history (also at ``self.history``).
+
+        An exception raised while iterating ``source`` ends the stream: the
+        panes already queued are processed, then ``run`` re-raises it."""
         if key is not None:
             self._root_key = key
         if self._root_key is None:
@@ -411,6 +417,9 @@ class StreamRuntime:
             self._producer = None
             self.flush()
             self._retire_all()
+        if self._producer_error is not None:
+            err, self._producer_error = self._producer_error, None
+            raise err
         return self._history
 
     def process(self, max_panes: int | None = None) -> list:

@@ -150,7 +150,9 @@ def bernoulli_sample(
     counts = stratum_counts(stratum_idx, num_slots)
     frac_k = jnp.broadcast_to(jnp.asarray(fraction, jnp.float32), (num_slots,))
     u = jax.random.uniform(key, stratum_idx.shape)
-    if backend == "pallas" and jax.default_backend() == "tpu":
+    from ..kernels.platform import on_tpu
+
+    if backend == "pallas" and on_tpu():
         from ..kernels.sample_mask import sample_mask as _kernel
 
         mask, weight = _kernel(stratum_idx, u, frac_k)

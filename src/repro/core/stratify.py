@@ -73,7 +73,9 @@ class StratumTable:
         quantize+Morton Pallas kernel on TPU (bit-identical to the jnp
         encoder, which remains the path everywhere else).
         """
-        if backend == "pallas" and jax.default_backend() == "tpu":
+        from ..kernels.platform import on_tpu
+
+        if backend == "pallas" and on_tpu():
             from ..kernels.geohash import geohash_encode
 
             codes = geohash_encode(lat, lon, self.precision)

@@ -114,8 +114,10 @@ def _fused_body(
         rows = jnp.concatenate(
             [rows, jnp.zeros((r_pad - r, rows.shape[1]), jnp.float32)], axis=0
         )
+    # value rows must not round to bf16 on the MXU: contract at full f32
     part = jax.lax.dot_general(
-        rows, member, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        rows, member, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # (r_pad, S_blk)
 
     rows_ref = out_refs[0]
@@ -137,6 +139,7 @@ def _fused_body(
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (b.shape[0], BINS_PAD), 1)
         binhot = (b[:, None] == iota_b).astype(jnp.float32)
         bins_parts.append(
+            # 0/1 operands are exact in one bf16 pass: default precision
             jax.lax.dot_general(
                 mk, binhot, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )  # (S_blk, BINS_PAD)
@@ -171,8 +174,10 @@ def _mega_kernel_latlon(
     lat_ref, lon_ref, codes_ref, vals_ref, ok_ref, scores_ref, thr_ref, *out_refs, spec
 ):
     n_step = pl.program_id(2)
-    code = encode(lat_ref[...].astype(jnp.float32), lon_ref[...].astype(jnp.float32), spec["precision"])
-    member = (code[:, None] == codes_ref[...][None, :]).astype(jnp.float32)
+    code = encode(
+        lat_ref[...][0].astype(jnp.float32), lon_ref[...][0].astype(jnp.float32), spec["precision"]
+    )
+    member = (code[:, None] == codes_ref[...]).astype(jnp.float32)
     vals = vals_ref[...].astype(jnp.float32)
     okv = ok_ref[...][0].astype(jnp.float32)
     keepv = _threshold_keep(member, okv, scores_ref[...][0].astype(jnp.float32), thr_ref[...][0])
@@ -243,12 +248,28 @@ def edge_megakernel_pallas(
 
     pad_n = (-n) % n_block
     s_pad = ((num_slots + s_block - 1) // s_block) * s_block
-    vals_p = jnp.pad(vals, ((0, 0), (0, pad_n)))
-    ok_p = jnp.pad(ok.astype(jnp.float32), ((0, 0), (0, pad_n)))
-    scores_p = jnp.pad(scores.astype(jnp.float32), ((0, 0), (0, pad_n)))
-    thr_p = jnp.pad(thresholds.astype(jnp.float32), ((0, 0), (0, s_pad - num_slots)))
     n_tot = n + pad_n
     grid = (m, s_pad // s_block, n_tot // n_block)
+
+    # Per-member rows travel as (M, 1, N) with the member axis squeezed out
+    # of the block, and per-tuple / per-slot vectors as (1, N) rows: every
+    # block's last two dims are then (1 == full extent, multiple of 128),
+    # which is the TPU tiling rule for any M and matches XLA's layout.
+    def member_rows(x, width, fill=0):
+        x = jnp.pad(x, ((0, 0), (0, width - x.shape[1])), constant_values=fill)
+        return x.reshape(m, 1, width)
+
+    member_n = pl.BlockSpec((None, 1, n_block), lambda m_, s, i: (m_, 0, i))
+    vals_p = jnp.pad(vals, ((0, 0), (0, pad_n)))
+    ok_p = member_rows(ok.astype(jnp.float32), n_tot)
+    scores_p = member_rows(scores.astype(jnp.float32), n_tot)
+    thr_p = member_rows(thresholds.astype(jnp.float32), s_pad)
+    shared_specs = [
+        pl.BlockSpec((c, n_block), lambda m_, s, i: (0, i)),
+        member_n,
+        member_n,
+        pl.BlockSpec((None, 1, s_block), lambda m_, s, i: (m_, 0, s)),
+    ]
 
     spec = dict(
         precision=precision, num_ext=num_ext, num_sk=num_sk,
@@ -256,17 +277,8 @@ def edge_megakernel_pallas(
     )
     if sidx is not None:
         kern = functools.partial(_mega_kernel_sidx, spec=spec)
-        ins = [
-            jnp.pad(sidx.astype(jnp.int32), ((0, 0), (0, pad_n)), constant_values=-1),
-            vals_p, ok_p, scores_p, thr_p,
-        ]
-        in_specs = [
-            pl.BlockSpec((1, n_block), lambda m_, s, i: (m_, i)),
-            pl.BlockSpec((c, n_block), lambda m_, s, i: (0, i)),
-            pl.BlockSpec((1, n_block), lambda m_, s, i: (m_, i)),
-            pl.BlockSpec((1, n_block), lambda m_, s, i: (m_, i)),
-            pl.BlockSpec((1, s_block), lambda m_, s, i: (m_, s)),
-        ]
+        ins = [member_rows(sidx.astype(jnp.int32), n_tot, fill=-1), vals_p, ok_p, scores_p, thr_p]
+        in_specs = [member_n] + shared_specs
     else:
         if lat is None or lon is None or codes is None or precision is None:
             raise ValueError("latlon mode needs lat, lon, codes and precision")
@@ -276,19 +288,13 @@ def edge_megakernel_pallas(
             constant_values=jnp.asarray(CODE_SENTINEL, jnp.uint32),
         )
         ins = [
-            jnp.pad(lat.astype(jnp.float32), (0, pad_n)),
-            jnp.pad(lon.astype(jnp.float32), (0, pad_n)),
-            codes_p, vals_p, ok_p, scores_p, thr_p,
+            jnp.pad(lat.astype(jnp.float32), (0, pad_n)).reshape(1, n_tot),
+            jnp.pad(lon.astype(jnp.float32), (0, pad_n)).reshape(1, n_tot),
+            codes_p.reshape(1, s_pad), vals_p, ok_p, scores_p, thr_p,
         ]
-        in_specs = [
-            pl.BlockSpec((n_block,), lambda m_, s, i: (i,)),
-            pl.BlockSpec((n_block,), lambda m_, s, i: (i,)),
-            pl.BlockSpec((s_block,), lambda m_, s, i: (s,)),
-            pl.BlockSpec((c, n_block), lambda m_, s, i: (0, i)),
-            pl.BlockSpec((1, n_block), lambda m_, s, i: (m_, i)),
-            pl.BlockSpec((1, n_block), lambda m_, s, i: (m_, i)),
-            pl.BlockSpec((1, s_block), lambda m_, s, i: (m_, s)),
-        ]
+        row_n = pl.BlockSpec((1, n_block), lambda m_, s, i: (0, i))
+        in_specs = [row_n, row_n, pl.BlockSpec((1, s_block), lambda m_, s, i: (0, s))]
+        in_specs += shared_specs
 
     out_shape = [jax.ShapeDtypeStruct((m, r_pad, s_pad), jnp.float32)]
     out_specs = [pl.BlockSpec((1, r_pad, s_block), lambda m_, s, i: (m_, 0, s))]

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from ...core.estimators import SKETCH_NUM_BINS, sketch_bin_index
 from ...core.geohash import encode
+from ..platform import on_tpu
 from .edge_megakernel import MegaResult, edge_megakernel_pallas
 
 
@@ -66,7 +67,7 @@ def edge_megakernel(
     """
     ext_idx, sk_idx = tuple(ext_idx), tuple(sk_idx)
     if interpret is None:
-        if jax.default_backend() != "tpu":
+        if not on_tpu():
             return _edge_megakernel_segment(
                 vals, ok, scores, thresholds, num_slots,
                 sidx=sidx, lat=lat, lon=lon, codes=codes, precision=precision,

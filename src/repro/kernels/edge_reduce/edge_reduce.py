@@ -56,12 +56,14 @@ def _moment_rows(values: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
 
 def _reduce_kernel(sidx_ref, rows_ref, out_ref):
     n_step = pl.program_id(1)
-    sidx = sidx_ref[...]  # (N_blk,)
+    sidx = sidx_ref[...][0]  # (N_blk,)
     s_base = pl.program_id(0) * S_BLOCK
     cols = s_base + jax.lax.broadcasted_iota(jnp.int32, (sidx.shape[0], S_BLOCK), 1)
     onehot = (sidx[:, None] == cols).astype(jnp.float32)
+    # value rows must not round to bf16 on the MXU: contract at full f32
     part = jax.lax.dot_general(
-        rows_ref[...], onehot, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        rows_ref[...], onehot, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # (R_pad, S_blk)
 
     @pl.when(n_step == 0)
@@ -94,16 +96,18 @@ def edge_reduce_pallas(
     pad_n = (-n) % N_BLOCK
     pad_r = (-r) % ROW_ALIGN
     s_slots = ((num_slots + S_BLOCK - 1) // S_BLOCK) * S_BLOCK
-    sidx = jnp.pad(stratum_idx.astype(jnp.int32), (0, pad_n), constant_values=-1)
+    # sidx travels as one (1, N) row: a (1, N_BLOCK) block is tiling-legal
+    # on the TPU, where a 1-D (N_BLOCK,) block mismatches XLA's layout
+    sidx = jnp.pad(stratum_idx.astype(jnp.int32), (0, pad_n), constant_values=-1)[None]
     rows = jnp.pad(rows, ((0, pad_r), (0, pad_n)))
     r_pad = rows.shape[0]
-    grid = (s_slots // S_BLOCK, sidx.shape[0] // N_BLOCK)
+    grid = (s_slots // S_BLOCK, sidx.shape[1] // N_BLOCK)
     out = pl.pallas_call(
         _reduce_kernel,
         out_shape=jax.ShapeDtypeStruct((r_pad, s_slots), jnp.float32),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((N_BLOCK,), lambda s, i: (i,)),
+            pl.BlockSpec((1, N_BLOCK), lambda s, i: (0, i)),
             pl.BlockSpec((r_pad, N_BLOCK), lambda s, i: (0, i)),
         ],
         out_specs=pl.BlockSpec((r_pad, S_BLOCK), lambda s, i: (0, s)),

@@ -18,13 +18,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..platform import on_tpu
 from .edge_reduce import _moment_rows, edge_reduce_pallas
 
 
 def edge_reduce(stratum_idx, values, mask, num_slots: int, interpret: bool | None = None):
     """-> (count (S,), s1 (C, S), s2 (C, S)) raw per-stratum power sums."""
     if interpret is None:
-        if jax.default_backend() != "tpu":
+        if not on_tpu():
             return _edge_reduce_segment(stratum_idx, values, mask, num_slots)
         interpret = False
     return edge_reduce_pallas(stratum_idx, values, mask, num_slots, interpret=interpret)

@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..platform import on_tpu
 from .flash_attention import KV_BLOCK, Q_BLOCK, flash_attention_pallas
 
 
@@ -28,7 +28,7 @@ def flash_attention(q, k, v):
     qf = prep(q, H)
     kf = prep(k, K)
     vf = prep(v, K)
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     o = flash_attention_pallas(qf, kf, vf, groups=G, scale=scale, interpret=interpret)
     o = o.reshape(B, H, s_p, dh_p).transpose(0, 2, 1, 3)
     return o[:, :S, :, :dh]

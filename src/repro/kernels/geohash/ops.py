@@ -7,14 +7,9 @@ works everywhere; neighborhood/stratum lookup stays outside the kernel
 
 from __future__ import annotations
 
-import jax
-
+from ..platform import on_tpu
 from .geohash import encode_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def geohash_encode(lat, lon, precision: int, block: int = 2048):
-    return encode_pallas(lat, lon, precision, block=block, interpret=not _on_tpu())
+    return encode_pallas(lat, lon, precision, block=block, interpret=not on_tpu())

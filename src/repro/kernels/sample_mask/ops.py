@@ -2,11 +2,9 @@
 
 from __future__ import annotations
 
-import jax
-
+from ..platform import on_tpu
 from .sample_mask import sample_mask_pallas
 
 
 def sample_mask(stratum_idx, uniforms, fractions):
-    interpret = jax.default_backend() != "tpu"
-    return sample_mask_pallas(stratum_idx, uniforms, fractions, interpret=interpret)
+    return sample_mask_pallas(stratum_idx, uniforms, fractions, interpret=not on_tpu())

@@ -30,14 +30,16 @@ N_BLOCK, S_BLOCK = kernel_blocks("sample_mask")
 
 def _select_kernel(sidx_ref, u_ref, frac_ref, mask_ref, w_ref, acc_ref, *, s_steps: int):
     s_step = pl.program_id(1)
-    sidx = sidx_ref[...]
+    sidx = sidx_ref[...]  # (1, N_blk)
     s_base = s_step * S_BLOCK
-    cols = s_base + jax.lax.broadcasted_iota(jnp.int32, (sidx.shape[0], S_BLOCK), 1)
-    onehot = (sidx[:, None] == cols).astype(jnp.float32)
+    rows = s_base + jax.lax.broadcasted_iota(jnp.int32, (S_BLOCK, sidx.shape[1]), 0)
+    onehot = (rows == sidx).astype(jnp.float32)  # (S_blk, N_blk), lane-dense
+    # fractions must not round to bf16 on the MXU (0.8 would become
+    # 0.80078125 and flip keep decisions): contract at full f32
     part = jax.lax.dot_general(
-        onehot, frac_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (N_blk,) gathered fractions from this strata block
+        frac_ref[...], onehot, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # (1, N_blk) gathered fractions from this strata block
 
     @pl.when(s_step == 0)
     def _init():
@@ -51,7 +53,7 @@ def _select_kernel(sidx_ref, u_ref, frac_ref, mask_ref, w_ref, acc_ref, *, s_ste
     def _emit():
         f = acc_ref[...]
         keep = u_ref[...] < f
-        mask_ref[...] = keep
+        mask_ref[...] = keep.astype(jnp.int32)
         w_ref[...] = jnp.where(keep, 1.0 / jnp.maximum(f, 1e-9), 0.0)
 
 
@@ -62,33 +64,32 @@ def sample_mask_pallas(
     fractions: jnp.ndarray,
     interpret: bool = False,
 ):
-    """(sidx (N,), u (N,), f_k (S,)) -> (mask (N,) bool, weight (N,) f32)."""
+    """(sidx (N,), u (N,), f_k (S,)) -> (mask (N,) bool, weight (N,) f32).
+
+    Every vector travels as one (1, len) row so each block is
+    ``(1, multiple of 128)``: tiling-legal on the TPU, where 1-D blocks
+    mismatch XLA's layout.
+    """
     n = stratum_idx.shape[0]
     s = fractions.shape[0]
     pad_n = (-n) % N_BLOCK
     pad_s = (-s) % S_BLOCK
-    sidx = jnp.pad(stratum_idx.astype(jnp.int32), (0, pad_n), constant_values=-1)
-    u = jnp.pad(uniforms.astype(jnp.float32), (0, pad_n), constant_values=2.0)
-    frac = jnp.pad(fractions.astype(jnp.float32), (0, pad_s))
-    s_steps = frac.shape[0] // S_BLOCK
-    grid = (sidx.shape[0] // N_BLOCK, s_steps)
+    sidx = jnp.pad(stratum_idx.astype(jnp.int32), (0, pad_n), constant_values=-1)[None]
+    u = jnp.pad(uniforms.astype(jnp.float32), (0, pad_n), constant_values=2.0)[None]
+    frac = jnp.pad(fractions.astype(jnp.float32), (0, pad_s))[None]
+    s_steps = frac.shape[1] // S_BLOCK
+    grid = (sidx.shape[1] // N_BLOCK, s_steps)
+    row_n = pl.BlockSpec((1, N_BLOCK), lambda i, s_: (0, i))
     mask, w = pl.pallas_call(
         functools.partial(_select_kernel, s_steps=s_steps),
         out_shape=(
-            jax.ShapeDtypeStruct(sidx.shape, jnp.bool_),
+            jax.ShapeDtypeStruct(sidx.shape, jnp.int32),
             jax.ShapeDtypeStruct(sidx.shape, jnp.float32),
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((N_BLOCK,), lambda i, s_: (i,)),
-            pl.BlockSpec((N_BLOCK,), lambda i, s_: (i,)),
-            pl.BlockSpec((S_BLOCK,), lambda i, s_: (s_,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((N_BLOCK,), lambda i, s_: (i,)),
-            pl.BlockSpec((N_BLOCK,), lambda i, s_: (i,)),
-        ),
-        scratch_shapes=[pltpu.VMEM((N_BLOCK,), jnp.float32)],
+        in_specs=[row_n, row_n, pl.BlockSpec((1, S_BLOCK), lambda i, s_: (0, s_))],
+        out_specs=(row_n, row_n),
+        scratch_shapes=[pltpu.VMEM((1, N_BLOCK), jnp.float32)],
         interpret=interpret,
     )(sidx, u, frac)
-    return mask[:n], w[:n]
+    return mask[0, :n].astype(jnp.bool_), w[0, :n]

@@ -2,11 +2,10 @@
 
 from __future__ import annotations
 
-import jax
-
+from ..platform import on_tpu
 from .stratified_stats import stratified_stats_pallas
 
 
 def stratified_stats(stratum_idx, values, mask, num_slots: int):
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     return stratified_stats_pallas(stratum_idx, values, mask, num_slots, interpret=interpret)

@@ -1,7 +1,10 @@
 """Single source of kernel tiling constants.
 
-Every Pallas kernel in this package tiles its inputs into ``(N_BLOCK,)``
-point blocks and ``(S_BLOCK,)`` stratum-slot blocks.  The per-kernel
+Every Pallas kernel in this package tiles its inputs into N_BLOCK-point
+blocks and S_BLOCK-slot blocks.  Per-point and per-slot vectors travel as
+``(1, N)`` / ``(1, S)`` rows cut into ``(1, block)`` blocks: on the TPU a
+block's last two dims must be a multiple of (8, 128) or the full extent,
+and 1-D blocks smaller than 1024 mismatch XLA's layout.  The per-kernel
 defaults used to be duplicated literals in each kernel module; they now
 live here so a TPU tuning pass edits one table (or installs a runtime
 override) instead of chasing copies.

@@ -11,9 +11,8 @@ device state (required so tests/benches see 1 device).
 
 from __future__ import annotations
 
-import jax
 
-from ..sharding.compat import compat_make_mesh, compat_shard_map as compat_shard_map  # re-export
+from ..sharding.compat import compat_make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

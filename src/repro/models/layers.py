@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding.compat import compat_shard_map
 
 from .base import ModelConfig, ParamSpec
 
@@ -275,7 +274,7 @@ def sharded_decode_attention(
     qspec = P(bspec, None, None, None)  # replicated over model (see local)
     cspec = P(bspec, "model", None, None)
     if fused_update:
-        mapped = compat_shard_map(
+        mapped = jax.shard_map(
             lambda qh, kc, vc, kn, vn, ln, wa: local(qh, kc, vc, kn, vn, ln, wa),
             mesh=mesh,
             in_specs=(qspec, cspec, cspec, P(bspec, None, None, None), P(bspec, None, None, None), P(), P()),
@@ -283,7 +282,7 @@ def sharded_decode_attention(
             check_vma=False,
         )
         return mapped(q, k_cache, v_cache, k_new, v_new, jnp.asarray(length), jnp.asarray(write_at))
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         lambda qh, kc, vc, ln: local(qh, kc, vc, None, None, ln, None),
         mesh=mesh,
         in_specs=(qspec, cspec, cspec, P()),

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding.compat import compat_shard_map
 
 from ..sharding.logical import active_rules
 from .base import ModelConfig, ParamSpec
@@ -161,7 +160,7 @@ def _moe_sharded(p: dict, x: jnp.ndarray, cfg: ModelConfig, rules):
     else:
         wspec_g = P(None, None, "model")
         wspec_d = P(None, "model", None)
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, None), wspec_g, wspec_g, wspec_d, bspec),

@@ -1,5 +1,4 @@
-"""Version-compat wrappers for mesh/shard_map APIs that moved across jax
-releases.  Dependency-free (only jax), so every layer may import it."""
+"""Mesh construction shared by every layer (depends only on jax)."""
 
 from __future__ import annotations
 
@@ -7,24 +6,10 @@ import jax
 
 
 def compat_make_mesh(shape, axes):
-    """`jax.make_mesh` across versions: `axis_types` appeared in newer jax;
-    older releases build an (implicitly Auto) mesh without it."""
-    if hasattr(jax.sharding, "AxisType"):
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``.
 
-
-def compat_shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """`shard_map` across versions: top-level `jax.shard_map(check_vma=...)`
-    on newer jax, `jax.experimental.shard_map.shard_map(check_rep=...)` on
-    older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the
+    shard_map'd edge programs and sharding-constrained layers would have to
+    spell out every output sharding; ``Auto`` leaves it to the compiler.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -8,9 +8,6 @@ Hypothesis profile selection (``HYPOTHESIS_PROFILE`` env var):
   * ``nightly`` — randomized search at 10x ``max_examples``, no deadline;
     the long-tail sweep PRs shouldn't pay for.
   * unset — hypothesis defaults: randomized local search.
-
-``tests/_hypothesis_fallback.py`` honors the same env var when hypothesis
-isn't installed (the container's tier-1 path).
 """
 
 import os
@@ -18,27 +15,24 @@ import os
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import HealthCheck, settings
+from hypothesis import HealthCheck, settings
 
-    settings.register_profile(
-        "ci",
-        derandomize=True,
-        print_blob=True,
-        deadline=None,
-        suppress_health_check=[HealthCheck.too_slow],
-    )
-    settings.register_profile(
-        "nightly",
-        max_examples=1000,
-        deadline=None,
-        suppress_health_check=[HealthCheck.too_slow],
-    )
-    _profile = os.environ.get("HYPOTHESIS_PROFILE")
-    if _profile:
-        settings.load_profile(_profile)
-except ImportError:  # local runs use tests/_hypothesis_fallback.py
-    pass
+settings.register_profile(
+    "ci",
+    derandomize=True,
+    print_blob=True,
+    deadline=None,
+    suppress_health_check=[HealthCheck.too_slow],
+)
+settings.register_profile(
+    "nightly",
+    max_examples=1000,
+    deadline=None,
+    suppress_health_check=[HealthCheck.too_slow],
+)
+_profile = os.environ.get("HYPOTHESIS_PROFILE")
+if _profile:
+    settings.load_profile(_profile)
 
 
 def pytest_configure(config):

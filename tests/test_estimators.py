@@ -3,10 +3,7 @@ merge associativity, raw == pre-aggregated equivalence."""
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

@@ -23,10 +23,7 @@ import pytest
 
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - exercised only without hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.estimators import SKETCH_NUM_BINS
 from repro.kernels.edge_megakernel import edge_megakernel

@@ -49,6 +49,7 @@ from repro.core.qdisc import (
     CAUSE_SHED,
     BoundedPaneQueue,
     DropLedger,
+    QueueClosed,
 )
 from repro.data.sources import BurstySource, PacedSource
 from repro.data.streams import shenzhen_taxi_stream
@@ -178,7 +179,7 @@ def test_close_drains_then_returns_none_and_rejects_puts():
     q.close()
     assert q.get(timeout=0).tag == "a"  # queued panes still drain
     assert q.get(timeout=0) is None
-    with pytest.raises(RuntimeError, match="closed"):
+    with pytest.raises(QueueClosed, match="closed"):
         q.put(_FakePane(2))
 
 
@@ -255,6 +256,26 @@ def test_run_without_key_raises(pipe, panes):
     sess.register(Q_MEANVAR)
     with pytest.raises(ValueError, match="PRNG key"):
         StreamRuntime(sess).run(panes[:1])
+
+
+@pytest.mark.parametrize("exc", [ValueError, RuntimeError])
+def test_source_error_mid_stream_is_reraised_after_queued_panes(pipe, panes, exc):
+    """A source that fails mid-stream must not end the run as if the stream
+    were over: the panes it delivered are processed, then ``run`` raises.
+    RuntimeError is the case that matters: JAX raises its runtime errors
+    as RuntimeErrors, and they must not pass for the queue-closed signal."""
+
+    def failing_source():
+        yield from panes[:2]
+        raise exc("sensor feed lost")
+
+    sess = StreamSession(pipe, initial_fraction=0.8)
+    sess.register(Q_MEANVAR)
+    rt = StreamRuntime(sess, key=jax.random.key(0), config=RuntimeConfig(policy="block"))
+    with pytest.raises(exc, match="sensor feed lost"):
+        rt.run(failing_source())
+    assert len(rt.history) == 2
+    assert rt.stats().panes_processed == 2
 
 
 def test_offer_process_drain_are_incremental_and_bounded(pipe, panes):

@@ -2,10 +2,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

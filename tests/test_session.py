@@ -7,10 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     SHENZHEN_BBOX,

@@ -33,7 +33,7 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core import make_table, contiguous_plan, SHENZHEN_BBOX
 from repro.core.routing import exchange
-from repro.launch.mesh import compat_make_mesh, compat_shard_map
+from repro.launch.mesh import compat_make_mesh
 
 mesh = compat_make_mesh((8,), ("data",))
 table = make_table(*SHENZHEN_BBOX, precision=5, neighborhood_precision=3)
@@ -47,7 +47,7 @@ def shard_fn(s, p):
     valid, rx_s, rx_p, dropped = exchange(plan, s, p, "data", capacity=256)
     return valid, rx_s, rx_p, dropped[None]
 
-mapped = jax.jit(compat_shard_map(shard_fn, mesh=mesh,
+mapped = jax.jit(jax.shard_map(shard_fn, mesh=mesh,
     in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data"), P("data"), P("data")),
     check_vma=False))
 valid, rx_s, rx_p, dropped = mapped(sidx, payload)
@@ -110,7 +110,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.train import compression
-from repro.launch.mesh import compat_make_mesh, compat_shard_map
+from repro.launch.mesh import compat_make_mesh
 
 mesh = compat_make_mesh((8,), ("pod",))
 rng = np.random.default_rng(0)
@@ -122,7 +122,7 @@ def shard_fn(g):
         {"g": g}, jax.random.key(0), 0.5, st, axis="pod")
     return red["g"]
 
-mapped = jax.jit(compat_shard_map(shard_fn, mesh=mesh, in_specs=(P("pod"),),
+mapped = jax.jit(jax.shard_map(shard_fn, mesh=mesh, in_specs=(P("pod"),),
                  out_specs=P("pod"), check_vma=False))
 out = np.asarray(mapped(g_global)).reshape(8, -1)
 # identical masks (shared key): every pod holds the same reduced value

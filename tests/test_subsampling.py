@@ -23,10 +23,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - CI installs hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     SHENZHEN_BBOX,
@@ -48,6 +45,11 @@ ROI_SOUTH = ((22.45, 22.65), (113.76, 114.64))
 ROI_NORTH = ((22.60, 22.86), (113.76, 114.64))  # overlaps ROI_SOUTH
 
 EXACT_FIELDS = ("value", "moe", "ci_low", "ci_high", "relative_error", "n", "population")
+
+
+def _f32(x: float) -> float:
+    """Nearest float32: hypothesis needs ``width=32`` bounds it can represent."""
+    return float(np.float32(x))
 
 
 @pytest.fixture(scope="module")
@@ -81,8 +83,8 @@ def _assert_estimates_equal(ind, got, aggs):
 
 @settings(deadline=None, max_examples=8)
 @given(
-    f_lo=st.floats(min_value=0.1, max_value=0.5, width=32),
-    f_hi=st.floats(min_value=0.55, max_value=1.0, width=32),
+    f_lo=st.floats(min_value=_f32(0.1), max_value=0.5, width=32),
+    f_hi=st.floats(min_value=_f32(0.55), max_value=1.0, width=32),
     seed=st.integers(min_value=0, max_value=2**31 - 1),
 )
 def test_srs_refined_members_match_independent_execute(pipe, window, f_lo, f_hi, seed):
@@ -112,8 +114,8 @@ def test_srs_refined_members_match_independent_execute(pipe, window, f_lo, f_hi,
 
 @settings(deadline=None, max_examples=8)
 @given(
-    f_a=st.floats(min_value=0.1, max_value=0.9, width=32),
-    f_b=st.floats(min_value=0.1, max_value=0.9, width=32),
+    f_a=st.floats(min_value=_f32(0.1), max_value=_f32(0.9), width=32),
+    f_b=st.floats(min_value=_f32(0.1), max_value=_f32(0.9), width=32),
     seed=st.integers(min_value=0, max_value=2**31 - 1),
 )
 def test_bernoulli_cross_roi_members_match_independent_execute(pipe, window, f_a, f_b, seed):
@@ -180,16 +182,17 @@ def test_bernoulli_raw_mode_keeps_separate_groups(pipe):
 
 @settings(deadline=None, max_examples=8)
 @given(
-    f_lo=st.floats(min_value=0.05, max_value=0.95, width=32),
-    f_hi=st.floats(min_value=0.05, max_value=0.95, width=32),
+    f_lo=st.floats(min_value=_f32(0.05), max_value=_f32(0.95), width=32),
+    f_hi=st.floats(min_value=_f32(0.05), max_value=_f32(0.95), width=32),
     seed=st.integers(min_value=0, max_value=2**31 - 1),
 )
-def test_nested_masks_are_subsets(rng, f_lo, f_hi, seed):
+def test_nested_masks_are_subsets(f_lo, f_hi, seed):
     """The shared-randomness masks are nested in the fraction: the
     lower-fraction sample is contained in the higher-fraction one, for both
     SRS ranks and Bernoulli uniforms — the property that lets one edge pass
     serve every member fraction."""
     f_lo, f_hi = sorted((f_lo, f_hi))
+    rng = np.random.default_rng(seed)
     sidx = jnp.asarray(rng.integers(0, 12, 4_000), jnp.int32)
     key = jax.random.key(seed)
     ranks, counts = sampling.srs_ranks(key, sidx, 13)
@@ -253,8 +256,8 @@ def test_refined_estimates_unbiased_against_truth(pipe):
 
 @settings(deadline=None, max_examples=8)
 @given(
-    f_lo=st.floats(min_value=0.1, max_value=0.45, width=32),
-    f_hi=st.floats(min_value=0.65, max_value=0.98, width=32),
+    f_lo=st.floats(min_value=_f32(0.1), max_value=_f32(0.45), width=32),
+    f_hi=st.floats(min_value=_f32(0.65), max_value=_f32(0.98), width=32),
     seed=st.integers(min_value=0, max_value=2**31 - 1),
 )
 def test_ci_widens_as_refined_fraction_shrinks(pipe, window, f_lo, f_hi, seed):

@@ -12,8 +12,7 @@ Device contexts are detected structurally:
 * functions decorated with ``jit`` / ``pallas_call`` / ``shard_map``
   (including ``partial(jax.jit, ...)`` forms);
 * functions passed by name to a jit-wrapping call in the same module
-  (``jax.jit(run)``, ``self._compiled(plan, run, ...)``, ``shard_map`` /
-  ``compat_shard_map``);
+  (``jax.jit(run)``, ``self._compiled(plan, run, ...)``, ``shard_map``);
 * the repo's pane-loop hot paths (``StreamSession.step/run/_emit``,
   ``EdgeCloudPipeline.run_stream``) plus any function whose ``def`` line
   carries a ``# edgelint: pane-loop`` marker.
@@ -48,8 +47,6 @@ JIT_WRAPPERS = {
     "jit",
     "pallas_call",
     "shard_map",
-    "compat_shard_map",
-    "_shard_map",
     "_compiled",  # EdgeCloudPipeline._compiled: jit or shard_map+jit
 }
 

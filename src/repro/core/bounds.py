@@ -193,22 +193,23 @@ def var_interval(
     ``[0, inf)``.  ``kurtosis`` (see :func:`sketch_kurtosis`) sharpens the
     s² resampling spread beyond normal theory for heavy-tailed columns.
     """
-    mean_r, s2_r = moment_replicates(
-        key, n, total, mean, s2, replicates, kurtosis=kurtosis
-    )
-    active = (n > 0) & (total > 0)
-    w = jnp.where(active, total, 0.0)
-    covered = jnp.maximum(_reduce(w, grp, num_groups), 1.0)
-    sum_r = _reduce(w * mean_r, grp, num_groups)
-    ey2_r = _reduce(w * (s2_r + mean_r * mean_r), grp, num_groups)
-    mean_g_r = sum_r / covered
-    var_r = jnp.maximum(ey2_r / covered - mean_g_r * mean_g_r, 0.0)
-    lo, hi = percentile_interval(var_r, confidence)
-    lo = jnp.maximum(lo, 0.0)
-    if unidentified is not None:
-        lo = jnp.where(unidentified, 0.0, lo)
-        hi = jnp.where(unidentified, jnp.inf, hi)
-    return lo, hi
+    with jax.named_scope("bounds"):
+        mean_r, s2_r = moment_replicates(
+            key, n, total, mean, s2, replicates, kurtosis=kurtosis
+        )
+        active = (n > 0) & (total > 0)
+        w = jnp.where(active, total, 0.0)
+        covered = jnp.maximum(_reduce(w, grp, num_groups), 1.0)
+        sum_r = _reduce(w * mean_r, grp, num_groups)
+        ey2_r = _reduce(w * (s2_r + mean_r * mean_r), grp, num_groups)
+        mean_g_r = sum_r / covered
+        var_r = jnp.maximum(ey2_r / covered - mean_g_r * mean_g_r, 0.0)
+        lo, hi = percentile_interval(var_r, confidence)
+        lo = jnp.maximum(lo, 0.0)
+        if unidentified is not None:
+            lo = jnp.where(unidentified, 0.0, lo)
+            hi = jnp.where(unidentified, jnp.inf, hi)
+        return lo, hi
 
 
 # Poisson-rate smoothing of occupied bins: a sparse bin's observed count c
@@ -283,11 +284,12 @@ def quantile_interval(
     ``bins`` is the merged (S+1, B) sampled-count histogram; each collapsed
     replicate (see :func:`collapsed_replicates`) re-inverts its CDF.
     """
-    _, wb_r = collapsed_replicates(
-        key, bins, n, total, replicates, grp=grp, num_groups=num_groups
-    )
-    q_r = sketch_quantile(wb_r, q)
-    return percentile_interval(q_r, confidence)
+    with jax.named_scope("bounds"):
+        _, wb_r = collapsed_replicates(
+            key, bins, n, total, replicates, grp=grp, num_groups=num_groups
+        )
+        q_r = sketch_quantile(wb_r, q)
+        return percentile_interval(q_r, confidence)
 
 
 def _hist_var(wb: jnp.ndarray) -> jnp.ndarray:
@@ -319,12 +321,13 @@ def var_sketch_interval(
     (the exact moment-based plug-in estimate), which cancels the constant
     ~bin-resolution bias between the binned and exact statistics.
     """
-    wb, wb_r = collapsed_replicates(
-        key, bins, n, total, replicates, grp=grp, num_groups=num_groups
-    )
-    var_0 = _hist_var(wb)
-    lo, hi = percentile_interval(_hist_var(wb_r), confidence)
-    return jnp.maximum(center + (lo - var_0), 0.0), center + (hi - var_0)
+    with jax.named_scope("bounds"):
+        wb, wb_r = collapsed_replicates(
+            key, bins, n, total, replicates, grp=grp, num_groups=num_groups
+        )
+        var_0 = _hist_var(wb)
+        lo, hi = percentile_interval(_hist_var(wb_r), confidence)
+        return jnp.maximum(center + (lo - var_0), 0.0), center + (hi - var_0)
 
 
 def _rank_slack(n: jnp.ndarray, total: jnp.ndarray, confidence: float) -> jnp.ndarray:
@@ -354,24 +357,25 @@ def extrema_interval(
     side is +/-inf for strata whose spread is unobservable (n_k < 2 while
     under-sampled, including sampled-empty populated strata).
     """
-    sign = 1.0 if side == "max" else -1.0
-    m = _rank_slack(n, total, confidence)
-    d = jnp.sqrt(s2 * jnp.maximum(total / jnp.maximum(m, 1.0) - 1.0, 0.0))
-    # work in signed space (negate for min) so both sides are maxima
-    witnessed = jnp.where(total > 0, sign * ext_value, -jnp.inf)
-    bound = jnp.where(m > 0, sign * mean + d, witnessed)
-    # spread unobservable: an under-sampled stratum with n_k < 2 admits no
-    # Cantelli bound — its population extreme is honestly unbounded
-    bound = jnp.where((m > 0) & (n < 2), jnp.inf, bound)
-    # the bound can never undercut the witnessed sample extreme, and empty
-    # populations contribute the lattice identity
-    bound = jnp.where(total > 0, jnp.maximum(bound, witnessed), -jnp.inf)
-    if grp is None:
-        far = jnp.max(bound)
-        near = jnp.max(witnessed)
-    else:
-        far = jax.ops.segment_max(bound, grp, num_segments=num_groups + 1)[:num_groups]
-        near = jax.ops.segment_max(witnessed, grp, num_segments=num_groups + 1)[:num_groups]
-    if side == "max":
-        return near, far
-    return -far, -near
+    with jax.named_scope("bounds"):
+        sign = 1.0 if side == "max" else -1.0
+        m = _rank_slack(n, total, confidence)
+        d = jnp.sqrt(s2 * jnp.maximum(total / jnp.maximum(m, 1.0) - 1.0, 0.0))
+        # work in signed space (negate for min) so both sides are maxima
+        witnessed = jnp.where(total > 0, sign * ext_value, -jnp.inf)
+        bound = jnp.where(m > 0, sign * mean + d, witnessed)
+        # spread unobservable: an under-sampled stratum with n_k < 2 admits no
+        # Cantelli bound — its population extreme is honestly unbounded
+        bound = jnp.where((m > 0) & (n < 2), jnp.inf, bound)
+        # the bound can never undercut the witnessed sample extreme, and empty
+        # populations contribute the lattice identity
+        bound = jnp.where(total > 0, jnp.maximum(bound, witnessed), -jnp.inf)
+        if grp is None:
+            far = jnp.max(bound)
+            near = jnp.max(witnessed)
+        else:
+            far = jax.ops.segment_max(bound, grp, num_segments=num_groups + 1)[:num_groups]
+            near = jax.ops.segment_max(witnessed, grp, num_segments=num_groups + 1)[:num_groups]
+        if side == "max":
+            return near, far
+        return -far, -near

@@ -924,7 +924,8 @@ def merge_accs(a: dict, b: dict) -> dict:
 
 def merge_accs_panes(stacked: dict) -> dict:
     """Vectorized pane merge of one column's stacked states (leading P axis)."""
-    return {k: accumulator(k).merge_panes(s) for k, s in stacked.items()}
+    with jax.named_scope("merge_panes"):
+        return {k: accumulator(k).merge_panes(s) for k, s in stacked.items()}
 
 
 def psum_accs(accs: dict, axis_names, shared: StratumStats | None = None) -> dict:

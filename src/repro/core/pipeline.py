@@ -199,51 +199,52 @@ def _accumulate_columns(
     on the ``"segment"`` oracle backend.  Non-moment kinds (extrema
     lattices, quantile sketches) accumulate via their registry entries.
     """
-    kinds_map = plan.column_kind_map
-    stats: dict = {c: {} for c in plan.columns}
-    if cfg.backend == "pallas":
-        from ..kernels.edge_reduce import edge_reduce
+    with jax.named_scope("reduce"):
+        kinds_map = plan.column_kind_map
+        stats: dict = {c: {} for c in plan.columns}
+        if cfg.backend == "pallas":
+            from ..kernels.edge_reduce import edge_reduce
 
-        stacked = jnp.stack([cols[c].astype(jnp.float32) for c in plan.columns])
-        cnt, s1, s2 = edge_reduce(sidx, stacked, mask, num_slots)
-        for i, c in enumerate(plan.columns):
-            stats[c]["moments"] = estimators.stats_from_raw_moments(
-                cnt, s1[i], s2[i], counts
-            )
-    elif cfg.backend == "fused":
-        # a given sample's moment/extrema/sketch rows in one megakernel
-        # sweep (sidx mode, keep == mask via the zero-score/one-threshold
-        # degenerate compare); kinds outside the fused set fall through to
-        # the registry loop below
-        from ..kernels.edge_megakernel import edge_megakernel
+            stacked = jnp.stack([cols[c].astype(jnp.float32) for c in plan.columns])
+            cnt, s1, s2 = edge_reduce(sidx, stacked, mask, num_slots)
+            for i, c in enumerate(plan.columns):
+                stats[c]["moments"] = estimators.stats_from_raw_moments(
+                    cnt, s1[i], s2[i], counts
+                )
+        elif cfg.backend == "fused":
+            # a given sample's moment/extrema/sketch rows in one megakernel
+            # sweep (sidx mode, keep == mask via the zero-score/one-threshold
+            # degenerate compare); kinds outside the fused set fall through to
+            # the registry loop below
+            from ..kernels.edge_megakernel import edge_megakernel
 
-        ext_idx, sk_idx = _kernel_layout(plan.columns, kinds_map)
-        res = edge_megakernel(
-            _stack_staged(cfg, plan.columns, cols),
-            mask.astype(jnp.float32)[None],
-            jnp.zeros((1,) + mask.shape, jnp.float32),
-            jnp.ones((1, num_slots), jnp.float32),
-            num_slots,
-            sidx=sidx[None],
-            ext_idx=ext_idx,
-            sk_idx=sk_idx,
-        )
-        stats = _stats_from_mega(
-            plan.columns, kinds_map, res, 0, res.keep[0], counts,
-            plan.columns, ext_idx, sk_idx,
-        )
-    else:
-        for c in plan.columns:
-            stats[c]["moments"] = estimators.MOMENTS.accumulate(
-                cols[c], sidx, mask, num_slots, counts=counts
+            ext_idx, sk_idx = _kernel_layout(plan.columns, kinds_map)
+            res = edge_megakernel(
+                _stack_staged(cfg, plan.columns, cols),
+                mask.astype(jnp.float32)[None],
+                jnp.zeros((1,) + mask.shape, jnp.float32),
+                jnp.ones((1, num_slots), jnp.float32),
+                num_slots,
+                sidx=sidx[None],
+                ext_idx=ext_idx,
+                sk_idx=sk_idx,
             )
-    for c in plan.columns:
-        for kind in kinds_map[c]:
-            if kind not in stats[c]:
-                stats[c][kind] = estimators.accumulator(kind).accumulate(
+            stats = _stats_from_mega(
+                plan.columns, kinds_map, res, 0, res.keep[0], counts,
+                plan.columns, ext_idx, sk_idx,
+            )
+        else:
+            for c in plan.columns:
+                stats[c]["moments"] = estimators.MOMENTS.accumulate(
                     cols[c], sidx, mask, num_slots, counts=counts
                 )
-    return stats
+        for c in plan.columns:
+            for kind in kinds_map[c]:
+                if kind not in stats[c]:
+                    stats[c][kind] = estimators.accumulator(kind).accumulate(
+                        cols[c], sidx, mask, num_slots, counts=counts
+                    )
+        return stats
 
 
 def _plan_fusable(plan: Plan) -> bool:
@@ -353,8 +354,9 @@ def _edge_program(
         n_truncated = jnp.maximum(kept - jnp.int32(min(cap, lat.shape[0])), 0)
         counts = sample.counts
         if axes is not None:
-            packed = tuple(jax.lax.all_gather(p, axes, tiled=True) for p in packed)
-            counts = jax.lax.psum(counts, axes)
+            with jax.named_scope("collective"):
+                packed = tuple(jax.lax.all_gather(p, axes, tiled=True) for p in packed)
+                counts = jax.lax.psum(counts, axes)
         v_ok, v_sidx = packed[0], packed[1]
         gathered = {c: packed[2 + i] for i, c in enumerate(plan.columns)}
         stats = _accumulate_columns(
@@ -396,20 +398,21 @@ def _member_reduce(
 def _consolidate(plan: Plan, stats, n_sampled, ok, valid, counts, axes):
     """Shared tail of every preagg path: the consolidating collective over
     accumulator states plus the sample/validity/overflow counters."""
-    if axes is not None:
-        merged: dict = {}
-        shared = None
-        for c in plan.columns:
-            merged[c] = estimators.psum_accs(stats[c], axes, shared=shared)
-            shared = shared if shared is not None else merged[c]["moments"]
-        stats = merged
-    n_valid = jnp.sum(ok.astype(jnp.int32))
-    n_overflow = counts[-1] + jnp.sum((valid & ~ok).astype(jnp.int32))
-    if axes is not None:
-        n_sampled = jax.lax.psum(n_sampled, axes)
-        n_valid = jax.lax.psum(n_valid, axes)
-        n_overflow = jax.lax.psum(n_overflow, axes)
-    return stats, n_sampled, n_valid, n_overflow
+    with jax.named_scope("collective"):
+        if axes is not None:
+            merged: dict = {}
+            shared = None
+            for c in plan.columns:
+                merged[c] = estimators.psum_accs(stats[c], axes, shared=shared)
+                shared = shared if shared is not None else merged[c]["moments"]
+            stats = merged
+        n_valid = jnp.sum(ok.astype(jnp.int32))
+        n_overflow = counts[-1] + jnp.sum((valid & ~ok).astype(jnp.int32))
+        if axes is not None:
+            n_sampled = jax.lax.psum(n_sampled, axes)
+            n_valid = jax.lax.psum(n_valid, axes)
+            n_overflow = jax.lax.psum(n_overflow, axes)
+        return stats, n_sampled, n_valid, n_overflow
 
 
 def _fused_member_program(
@@ -749,7 +752,9 @@ class EdgeCloudPipeline:
     def _compiled(self, plan: Plan, body, out_template, sharded: bool):
         """Jit ``body(key, lat, lon, cols, valid, fraction, axes=None)`` —
         directly, or wrapped in shard_map over the data axes (shards = edge
-        nodes, replicated outputs shaped like ``out_template``)."""
+        nodes, replicated outputs shaped like ``out_template``).  Either way
+        the program is named after ``body`` (``jit_edge_pass`` in the HLO,
+        ``PjitFunction(edge_pass)`` on the host trace)."""
         if not sharded:
             return jax.jit(body)
         axes = self.axis_names
@@ -771,7 +776,7 @@ class EdgeCloudPipeline:
         plan = self.plan(query)
         table, cfg = self.table, self.config
 
-        def run(key, lat, lon, cols, valid, fraction, axes=None):
+        def execute(key, lat, lon, cols, valid, fraction, axes=None):
             stats, n_sampled, n_valid, n_overflow, n_truncated, comm = _edge_program(
                 plan, table, cfg, key, lat, lon, cols, valid, fraction, axes=axes
             )
@@ -787,7 +792,7 @@ class EdgeCloudPipeline:
                 comm_bytes=comm,
             )
 
-        fn = self._compiled(plan, run, _result_template(plan), sharded)
+        fn = self._compiled(plan, execute, _result_template(plan), sharded)
         self._execs[(query, sharded)] = fn
         return fn
 
@@ -807,13 +812,13 @@ class EdgeCloudPipeline:
             return fn
         table, cfg = self.table, self.config
 
-        def run(key, lat, lon, cols, valid, fraction, axes=None):
+        def edge_pass(key, lat, lon, cols, valid, fraction, axes=None):
             return _edge_program(
                 plan, table, cfg, key, lat, lon, cols, valid, fraction, axes=axes
             )
 
         template = (_stats_template(plan), 0, 0, 0, 0, 0)
-        fn = self._compiled(plan, run, template, sharded)
+        fn = self._compiled(plan, edge_pass, template, sharded)
         self._passes[(plan, sharded)] = fn
         return fn
 
@@ -831,13 +836,13 @@ class EdgeCloudPipeline:
             return fn
         table, cfg = self.table, self.config
 
-        def run(key, lat, lon, cols, valid, fractions, axes=None):
+        def refined_pass(key, lat, lon, cols, valid, fractions, axes=None):
             return _fused_edge_program(
                 fused, table, cfg, key, lat, lon, cols, valid, fractions, axes=axes
             )
 
         template = (tuple((_stats_template(p), 0, 0, 0) for p in fused.members), 0)
-        fn = self._compiled(fused.shared, run, template, sharded)
+        fn = self._compiled(fused.shared, refined_pass, template, sharded)
         self._refined_passes[cache_key] = fn
         return fn
 
@@ -850,18 +855,18 @@ class EdgeCloudPipeline:
 
         if num_panes == 1:
 
-            def run(stats, bkey):
+            def finalize(stats, bkey):
                 return aqp.finalize(plan, table, stats, key=bkey), stats
 
         else:
 
-            def run(stacked, bkey):
+            def finalize(stacked, bkey):
                 merged = {
                     c: estimators.merge_accs_panes(stacked[c]) for c in plan.columns
                 }
                 return aqp.finalize(plan, table, merged, key=bkey), merged
 
-        return run
+        return finalize
 
     def finalize_fn(self, plan: Plan, num_panes: int):
         """Jitted cloud-side emit for one registration, cached by *finalize
@@ -895,11 +900,11 @@ class EdgeCloudPipeline:
             return fn
         body = jax.vmap(self._finalize_body(plan, num_panes), in_axes=(0, None))
 
-        def run(member_stats, bkey):
+        def finalize_batched(member_stats, bkey):
             stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *member_stats)
             return body(stacked, bkey)
 
-        fn = jax.jit(run)
+        fn = jax.jit(finalize_batched)
         self._finalizers[key] = fn
         return fn
 

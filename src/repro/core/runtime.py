@@ -38,9 +38,10 @@ compute on one thread.  :class:`StreamRuntime` is that driver done right:
     restore resumes bit-identically to an uninterrupted run even when the
     ingest queue was non-empty at snapshot time;
   * :class:`RuntimeStats` observability: per-pane ingest/stage/dispatch
-    latency histograms + percentiles, queue high-water mark, drops by
-    cause, and overlap efficiency (compute-busy wall fraction) — consumed
-    by ``benchmarks/ingest_throughput.py`` and gated in CI.
+    latency percentiles, queue high-water mark, drops by cause, overlap
+    efficiency (compute-busy wall fraction), and the totals of the host
+    spans and counters (:mod:`.spans`) recorded on the pane loop and the
+    producer thread while this runtime drives them.
 
 Determinism: the runtime derives pane k's PRNG key as
 ``jax.random.fold_in(root_key, k)`` (the checkpoint-replay discipline), so
@@ -62,7 +63,7 @@ from typing import Callable, Iterator, Protocol, runtime_checkable
 import jax
 import numpy as np
 
-from . import feedback
+from . import feedback, spans
 from .feedback import EventPolicy, EventState
 from .qdisc import BoundedPaneQueue, DropLedger, QueueClosed
 from .windows import WindowBatch
@@ -101,6 +102,9 @@ class RuntimeConfig:
     shed_min_fraction: float = 0.05
     shed_decimate: int = 0  # while shedding admit 1 of every k panes (0=off)
     clock: Callable[[], float] = time.perf_counter
+
+
+_END = object()  # the producer's end-of-source marker
 
 
 @dataclasses.dataclass
@@ -149,30 +153,12 @@ class PaneTiming:
 
     pane_index: int
     ingest_s: float  # producer: source iteration time for this pane
-    queue_wait_s: float  # enqueue -> dequeue
-    stage_s: float  # dequeue -> device_put issued
-    dispatch_s: float  # session.step host time (async dispatch cost)
+    queue_wait_s: float  # enqueue -> dequeue (end of runtime.queue_get)
+    stage_s: float  # dequeue -> device_put issued (end of runtime.stage)
+    dispatch_s: float  # the runtime.dispatch span: events, shedding, session.step
     latency_s: float  # enqueue -> retired (end-to-end pane latency)
     t_dispatch: float
     t_retired: float
-
-
-_HIST_EDGES_MS = tuple(0.25 * 2.0**k for k in range(16))  # 0.25ms .. ~8.2s
-
-
-def _histogram_ms(values_s) -> dict:
-    """Log-bucketed latency histogram: upper-edge-ms -> count (+inf tail)."""
-    counts = {f"{edge:g}": 0 for edge in _HIST_EDGES_MS}
-    counts["inf"] = 0
-    for v in values_s:
-        ms = v * 1e3
-        for edge in _HIST_EDGES_MS:
-            if ms <= edge:
-                counts[f"{edge:g}"] += 1
-                break
-        else:
-            counts["inf"] += 1
-    return counts
 
 
 def _percentiles(values_s) -> dict:
@@ -194,6 +180,11 @@ class RuntimeStats:
     ``overlap_efficiency`` is compute-busy wall fraction: the union of the
     in-flight intervals [dispatch, retire] over the span from first dispatch
     to last retire — 1.0 means the device never waited on ingest.
+
+    ``spans`` is ``{name: {"count", "total_s", "self_s"}}`` and ``counters``
+    ``{name: int}``: what the runtime's :class:`~.spans.Recorder` saw on the
+    pane loop and the producer thread (``runtime.*``, ``session.*``,
+    ``compiles.<span>``, ``cache_loads.<span>``).
     """
 
     panes_processed: int
@@ -210,7 +201,6 @@ class RuntimeStats:
     stage: dict
     dispatch: dict
     pane_latency: dict
-    histograms: dict
     # pipeline compiled-program cache counters (per jit family hit/miss plus
     # the aggregate compile_count) — the multi-tenant churn contract's
     # observability surface; empty when the session exposes no pipeline
@@ -219,6 +209,8 @@ class RuntimeStats:
     # when an uplink codec is configured, the analytic dense model otherwise)
     # plus the codec fingerprint the figure was measured under (None = dense)
     uplink: dict = dataclasses.field(default_factory=dict)
+    spans: dict = dataclasses.field(default_factory=dict)
+    counters: dict = dataclasses.field(default_factory=dict)
 
     @property
     def dropped_tuples(self) -> int:
@@ -270,6 +262,7 @@ class StreamRuntime:
         self.config = config or RuntimeConfig()
         self.queue = BoundedPaneQueue(self.config.queue_capacity, self.config.policy)
         self._clock = self.config.clock
+        self._recorder = spans.Recorder(self._clock)
         self._root_key = key
         self._history: list = []
         self._timings: list[PaneTiming] = []
@@ -377,20 +370,23 @@ class StreamRuntime:
         return self.queue.put(_Arrival(pane, t, 0.0), timeout=timeout)
 
     def _pump(self, source: Source) -> None:
-        clock = self._clock
-        t_prev = clock()
-        try:
-            for pane in source:
-                t = clock()
-                self.queue.put(_Arrival(pane, t, t - t_prev))
-                t_prev = clock()
-        except QueueClosed:
-            return  # the consumer stopped early and closed the queue
-        except Exception as e:  # the thread's boundary: run() re-raises it
-            self._producer_error = e
-        finally:
-            if not self.queue.closed:
-                self.queue.close()
+        with self._recorder.active():
+            try:
+                panes = iter(source)
+                while True:
+                    with spans.span("runtime.source") as got:
+                        pane = next(panes, _END)
+                    if pane is _END:
+                        break
+                    with spans.span("runtime.queue_put") as put:
+                        self.queue.put(_Arrival(pane, put.t0, got.t1 - got.t0))
+            except QueueClosed:
+                return  # the consumer stopped early and closed the queue
+            except Exception as e:  # the thread's boundary: run() re-raises it
+                self._producer_error = e
+            finally:
+                if not self.queue.closed:
+                    self.queue.close()
 
     # -- the pane loop (EDG002-policed: no host syncs here) ------------------
 
@@ -408,15 +404,16 @@ class StreamRuntime:
             target=self._pump, args=(source,), name="stream-runtime-pump", daemon=True
         )
         self._producer.start()
-        try:
-            self._consume(wait=True, max_panes=max_panes)
-        finally:
-            if not self.queue.closed:
-                self.queue.close()  # early stop: unblock + terminate the producer
-            self._producer.join()
-            self._producer = None
-            self.flush()
-            self._retire_all()
+        with self._recorder.active():
+            try:
+                self._consume(wait=True, max_panes=max_panes)
+            finally:
+                if not self.queue.closed:
+                    self.queue.close()  # early stop: unblock + terminate the producer
+                self._producer.join()
+                self._producer = None
+                self.flush()
+                self._retire_all()
         if self._producer_error is not None:
             err, self._producer_error = self._producer_error, None
             raise err
@@ -429,27 +426,32 @@ class StreamRuntime:
         :meth:`drain` for a full barrier.  Returns steps emitted this call.
         """
         before = len(self._history)
-        self._consume(wait=False, max_panes=max_panes)
+        with self._recorder.active():
+            self._consume(wait=False, max_panes=max_panes)
         return self._history[before:]
 
+    def _next_pane_index(self) -> int:
+        """Session pane index the next dequeued pane will be stepped as."""
+        return self.session.pane_index + (self._staged is not None)
+
     def _consume(self, wait: bool, max_panes: int | None = None) -> None:
-        clock = self._clock
         n = 0
         while max_panes is None or n < max_panes:
             if wait:
                 timeout = self.config.stage_flush_s if self._staged is not None else None
             else:
                 timeout = 0.0
-            arrival = self.queue.get(timeout=timeout)
+            with spans.span("runtime.queue_get", pane=self._next_pane_index()) as got:
+                arrival = self.queue.get(timeout=timeout)
             if arrival is None:
                 if not wait or self.queue.closed:
                     break
                 # get() timed out with a pane staged and no successor in
                 # sight: flush it rather than trade latency for overlap
+                spans.count("runtime.timeout_flushes")
                 self.flush()
                 continue
-            t_deq = clock()
-            staged = self._stage(arrival, t_deq)
+            staged = self._stage(arrival, got.t1)
             if self._staged is not None:
                 # double buffer: dispatch pane k while pane k+1's H2D
                 # transfer (issued above) proceeds asynchronously
@@ -462,42 +464,46 @@ class StreamRuntime:
     def _stage(self, arrival: _Arrival, t_dequeue: float) -> _Staged:
         """Issue the pane's host→device transfers (async on real backends)."""
         pane = arrival.pane
-        staged = dataclasses.replace(
-            pane,
-            lat=jax.device_put(pane.lat),
-            lon=jax.device_put(pane.lon),
-            value=jax.device_put(pane.value),
-            valid=jax.device_put(pane.valid),
-            extra={k: jax.device_put(v) for k, v in pane.extra.items()},
-        )
-        return _Staged(arrival, staged, t_dequeue, self._clock())
+        with spans.span("runtime.stage", pane=self._next_pane_index()) as sp:
+            staged = dataclasses.replace(
+                pane,
+                lat=jax.device_put(pane.lat),
+                lon=jax.device_put(pane.lon),
+                value=jax.device_put(pane.value),
+                valid=jax.device_put(pane.valid),
+                extra={k: jax.device_put(v) for k, v in pane.extra.items()},
+            )
+        return _Staged(arrival, staged, t_dequeue, sp.t1)
 
     def _dispatch(self, staged: _Staged) -> None:
         """Feed one staged pane to the session — pure async dispatch."""
         arrival, pane = staged.arrival, staged.pane
-        ledger = self.queue.take_drops()
-        if ledger:
-            pane = self._attach_drops(pane, ledger)
-        self._apply_events()
-        self._maybe_shed()
-        key = jax.random.fold_in(self._root_key, self.session.pane_index)
-        t0 = self._clock()
-        step = self.session.step(key, pane)
-        t1 = self._clock()
+        with spans.span("runtime.dispatch", pane=self.session.pane_index) as sp:
+            ledger = self.queue.take_drops()
+            if ledger:
+                pane = self._attach_drops(pane, ledger)
+            self._apply_events()
+            self._maybe_shed()
+            key = jax.random.fold_in(self._root_key, self.session.pane_index)
+            step = self.session.step(key, pane)
+            # drop the pane's device inputs inside the span: freeing device
+            # buffers takes host time that should be put down to this pane
+            pane = staged.pane = None
+            self._n_tuples += arrival.size
+            self._queue_events(step)
+            self._history.append(step)
+            markers = self._markers(step)
         if self._t_first is None:
-            self._t_first = t0
-        self._n_tuples += arrival.size
-        self._queue_events(step)
-        self._history.append(step)
+            self._t_first = sp.t0
         self._inflight.append(
             _InFlight(
                 pane_index=step.pane_index,
                 arrival=arrival,
                 t_dequeue=staged.t_dequeue,
                 t_staged=staged.t_staged,
-                t_dispatch=t0,
-                t_dispatched=t1,
-                markers=self._markers(step),
+                t_dispatch=sp.t0,
+                t_dispatched=sp.t1,
+                markers=markers,
             )
         )
         while len(self._inflight) > self.config.max_inflight:
@@ -507,7 +513,8 @@ class StreamRuntime:
         """Dispatch the currently staged pane, if any."""
         if self._staged is not None:
             staged, self._staged = self._staged, None
-            self._dispatch(staged)
+            with self._recorder.active():
+                self._dispatch(staged)
 
     def _markers(self, step) -> object:
         """Device values that complete exactly when this pane's work does:
@@ -536,9 +543,10 @@ class StreamRuntime:
         timing.  This is the runtime's only ``block_until_ready`` — it
         bounds device memory in flight and timestamps completion, and by
         construction the pane is (nearly) always already done."""
-        jax.block_until_ready(entry.markers)
-        t = self._clock()
-        self._t_last = t
+        with spans.span("runtime.retire", pane=entry.pane_index) as sp:
+            jax.block_until_ready(entry.markers)
+            entry.markers = None  # freeing the pane's buffers costs ms: count it here
+        t = self._t_last = sp.t1
         self._timings.append(
             PaneTiming(
                 pane_index=entry.pane_index,
@@ -562,10 +570,11 @@ class StreamRuntime:
         """Process everything queued *now*, flush the staged pane, and
         retire all in-flight work (a full pipeline barrier).  Bounded: panes
         admitted after entry are left for the next call."""
-        budget = self.queue.depth + (1 if self._staged is not None else 0)
-        steps = self.process(max_panes=budget) if budget else []
-        self.flush()
-        self._retire_all()
+        with self._recorder.active():
+            budget = self.queue.depth + (1 if self._staged is not None else 0)
+            steps = self.process(max_panes=budget) if budget else []
+            self.flush()
+            self._retire_all()
         return steps
 
     def checkpoint(self, path=None, keep_last: int | None = None) -> dict:
@@ -589,13 +598,6 @@ class StreamRuntime:
             if self._t_first is not None and self._t_last is not None
             else 0.0
         )
-        series = {
-            "ingest": [t.ingest_s for t in timings],
-            "queue_wait": [t.queue_wait_s for t in timings],
-            "stage": [t.stage_s for t in timings],
-            "dispatch": [t.dispatch_s for t in timings],
-            "pane_latency": [t.latency_s for t in timings],
-        }
         return RuntimeStats(
             panes_processed=len(self._history),
             panes_enqueued=self.queue.total_put,
@@ -606,12 +608,11 @@ class StreamRuntime:
             shed_panes=self.shed_panes,
             overlap_efficiency=_overlap_efficiency(timings),
             wall_s=wall,
-            ingest=_percentiles(series["ingest"]),
-            queue_wait=_percentiles(series["queue_wait"]),
-            stage=_percentiles(series["stage"]),
-            dispatch=_percentiles(series["dispatch"]),
-            pane_latency=_percentiles(series["pane_latency"]),
-            histograms={k: _histogram_ms(v) for k, v in series.items()},
+            ingest=_percentiles([t.ingest_s for t in timings]),
+            queue_wait=_percentiles([t.queue_wait_s for t in timings]),
+            stage=_percentiles([t.stage_s for t in timings]),
+            dispatch=_percentiles([t.dispatch_s for t in timings]),
+            pane_latency=_percentiles([t.latency_s for t in timings]),
             compile_cache=(
                 pipe.cache_snapshot()
                 if (pipe := getattr(self.session, "pipe", None)) is not None
@@ -635,4 +636,6 @@ class StreamRuntime:
                     else None
                 ),
             },
+            spans=self._recorder.spans(),
+            counters=self._recorder.counters(),
         )

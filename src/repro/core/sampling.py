@@ -116,7 +116,8 @@ def srs_ranks(key, stratum_idx: jnp.ndarray, num_slots: int):
     subsampling (the nested SRS of per-query fraction refinement), and the
     refined sample is bit-identical to the member's independent draw.
     """
-    return _rank_within_stratum(key, stratum_idx, num_slots)
+    with jax.named_scope("sample"):
+        return _rank_within_stratum(key, stratum_idx, num_slots)
 
 
 def srs_sample(
@@ -185,18 +186,19 @@ def edgesos(
       stddev: per-stratum std estimates (required for 'neyman').
       backend: 'segment' | 'pallas' (fused Bernoulli selection kernel on TPU).
     """
-    if method == "bernoulli":
-        return bernoulli_sample(key, stratum_idx, num_slots, fraction, backend=backend)
-    counts = stratum_counts(stratum_idx, num_slots)
-    if method == "srs":
-        n_k = allocate_proportional(counts, fraction)
-    elif method == "neyman":
-        if stddev is None:
-            raise ValueError("neyman allocation requires per-stratum stddev")
-        n_k = allocate_neyman(counts, stddev, fraction, min_per_stratum)
-    else:
-        raise ValueError(f"unknown method {method!r}")
-    return srs_sample(key, stratum_idx, num_slots, n_k, counts)
+    with jax.named_scope("sample"):
+        if method == "bernoulli":
+            return bernoulli_sample(key, stratum_idx, num_slots, fraction, backend=backend)
+        counts = stratum_counts(stratum_idx, num_slots)
+        if method == "srs":
+            n_k = allocate_proportional(counts, fraction)
+        elif method == "neyman":
+            if stddev is None:
+                raise ValueError("neyman allocation requires per-stratum stddev")
+            n_k = allocate_neyman(counts, stddev, fraction, min_per_stratum)
+        else:
+            raise ValueError(f"unknown method {method!r}")
+        return srs_sample(key, stratum_idx, num_slots, n_k, counts)
 
 
 def compact(mask: jnp.ndarray, max_out: int, *arrays: jnp.ndarray):

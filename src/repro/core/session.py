@@ -95,6 +95,7 @@ import jax.numpy as jnp
 from . import codec as wirecodec
 from . import feedback
 from . import query as aqp
+from . import spans
 from .feedback import SLO, ControllerState
 from .query import (
     FusedPlan,
@@ -530,10 +531,12 @@ class StreamSession:
         one deliberate device sync: the uplink serialization boundary
         itself, where states become wire bytes by definition.
         """
-        stream = grp._codec.get(slot)
-        if stream is None:
-            stream = grp._codec[slot] = self.pipe.codec_spec.for_stream()
-        return wirecodec.roundtrip(stream, stats)
+        with spans.span("session.codec", pane=self.pane_index):
+            stream = grp._codec.get(slot)
+            if stream is None:
+                stream = grp._codec[slot] = self.pipe.codec_spec.for_stream()
+            spans.count("session.host_syncs")
+            return wirecodec.roundtrip(stream, stats)
 
     def _window_counters(self, reg: Registration) -> tuple:
         """This query's window-level counters, summed over its pane ring
@@ -568,9 +571,11 @@ class StreamSession:
         panes = reg.ring
         if len(panes) == 1:
             return panes[0].stats
-        return jax.tree.map(
+        stacked = jax.tree.map(
             lambda *xs: jnp.stack(xs, axis=0), *[p.stats for p in panes]
         )
+        spans.count("session.host_stacked_leaves", len(jax.tree.leaves(stacked)))
+        return stacked
 
     def _emit(self, reg: Registration, key) -> QueryResult:
         """Assemble this query's window from its pane ring and finalize.
@@ -580,6 +585,7 @@ class StreamSession:
         session bounds are bit-identical to an independent ``execute``."""
         fn = self.pipe.finalize_fn(reg.plan, len(reg.ring))
         estimates, stats = fn(self._window_stats(reg), key)
+        spans.count("session.finalize_dispatches")
         n_sampled, n_valid, n_overflow, n_truncated, comm, dropped = (
             self._window_counters(reg)
         )
@@ -610,6 +616,7 @@ class StreamSession:
         member_stats = member_stats + [member_stats[0]] * (b_pad - b)
         fn = self.pipe.batched_finalize_fn(regs[0].plan, num_panes, b_pad)
         estimates, stats = fn(member_stats, key)
+        spans.count("session.finalize_dispatches")
         counters = tuple(self._window_counters(reg) for reg in regs)
         return _EmitBatch(
             regs=tuple(regs), estimates=estimates, stats=stats, counters=counters
@@ -709,89 +716,97 @@ class StreamSession:
         emitted = _LazyResults()
         due: list[Registration] = []
         comm_total = 0
-        for grp in list(self._fusion_groups.values()):
+        for g, grp in enumerate(list(self._fusion_groups.values())):
             members = grp.members
             fused = grp.fused_plan()
             fractions = [r.fraction for r in members]
-            lat, lon, cols, valid = self.pipe._window_arrays(pane, fused.shared)
-            if self._refines(fused, fractions):
-                fn = grp._refined_fn
-                if fn is None:
-                    fn = grp._refined_fn = self.pipe._refined_pass_fn(
-                        fused, self.sharded
+            refined = self._refines(fused, fractions)
+            with spans.span(
+                "session.pass",
+                pane=self.pane_index,
+                group=g,
+                program="refined" if refined else "shared",
+            ):
+                lat, lon, cols, valid = self.pipe._window_arrays(pane, fused.shared)
+                if refined:
+                    fn = grp._refined_fn
+                    if fn is None:
+                        fn = grp._refined_fn = self.pipe._refined_pass_fn(
+                            fused, self.sharded
+                        )
+                    outs, _ = fn(
+                        key, lat, lon, cols, valid, jnp.asarray(fractions, jnp.float32)
                     )
-                outs, _ = fn(
-                    key, lat, lon, cols, valid, jnp.asarray(fractions, jnp.float32)
-                )
-                zero = jnp.int32(0)  # refined pass is preagg-only: no buffer
-                if self.pipe.codec_spec is not None:
-                    # refined passes ship one encoded frame per member (each
-                    # member's thinned states are its own uplink stream)
-                    shipped = [
-                        self._codec_ship(grp, reg.qid, st)
-                        for reg, (st, _ns, _nv, _no) in zip(members, outs)
-                    ]
-                    comm = sum(nb for _st, nb in shipped)
-                    per_member = [
-                        (st, ns, nv, no, zero, nb)
-                        for (st, nb), (_st, ns, nv, no) in zip(shipped, outs)
-                    ]
+                    zero = jnp.int32(0)  # refined pass is preagg-only: no buffer
+                    if self.pipe.codec_spec is not None:
+                        # refined passes ship one encoded frame per member (each
+                        # member's thinned states are its own uplink stream)
+                        shipped = [
+                            self._codec_ship(grp, reg.qid, st)
+                            for reg, (st, _ns, _nv, _no) in zip(members, outs)
+                        ]
+                        comm = sum(nb for _st, nb in shipped)
+                        per_member = [
+                            (st, ns, nv, no, zero, nb)
+                            for (st, nb), (_st, ns, nv, no) in zip(shipped, outs)
+                        ]
+                    else:
+                        comm = aqp.refined_preagg_bytes(fused, self.pipe.table.num_slots)
+                        per_member = [
+                            (st, ns, nv, no, zero, comm) for st, ns, nv, no in outs
+                        ]
                 else:
-                    comm = aqp.refined_preagg_bytes(fused, self.pipe.table.num_slots)
-                    per_member = [
-                        (st, ns, nv, no, zero, comm) for st, ns, nv, no in outs
-                    ]
-            else:
-                fn = grp._pass_fn
-                if fn is None:
-                    fn = grp._pass_fn = self.pipe._pass_fn(fused.shared, self.sharded)
-                stats, n_sampled, n_valid, n_overflow, n_truncated, _ = fn(
-                    key, lat, lon, cols, valid, jnp.float32(max(fractions))
-                )
-                if self.pipe.codec_spec is not None and fused.mode == "preagg":
-                    # one encoded union frame serves the whole group; the
-                    # members below carve the *decoded* states, so their
-                    # estimates reflect exactly what crossed the wire
-                    stats, comm = self._codec_ship(grp, "shared", stats)
-                else:
-                    # analytic, host-side: avoid syncing on the device pass
-                    comm = self._analytic_comm(fused, lat.shape[0])
-                per_member = []
-                for reg in members:
-                    kinds_map = reg.plan.column_kind_map
-                    # carve this query's columns *and* accumulator kinds
-                    # out of the shared pass's union states
-                    carved = {
-                        c: {k: stats[c][k] for k in kinds_map[c]}
-                        for c in reg.plan.columns
-                    }
-                    per_member.append(
-                        (carved, n_sampled, n_valid, n_overflow, n_truncated, comm)
+                    fn = grp._pass_fn
+                    if fn is None:
+                        fn = grp._pass_fn = self.pipe._pass_fn(fused.shared, self.sharded)
+                    stats, n_sampled, n_valid, n_overflow, n_truncated, _ = fn(
+                        key, lat, lon, cols, valid, jnp.float32(max(fractions))
                     )
-            comm_total += comm
-            self.total_passes += 1
-            for reg, (stats_m, n_s, n_v, n_o, n_t, comm_m) in zip(members, per_member):
-                reg.ring.append(
-                    _Pane(
-                        stats=stats_m,
-                        n_sampled=n_s,
-                        n_valid=n_v,
-                        n_overflow=n_o,
-                        n_truncated=n_t,
-                        n_dropped=n_dropped,
-                        comm_bytes=comm_m,
+                    if self.pipe.codec_spec is not None and fused.mode == "preagg":
+                        # one encoded union frame serves the whole group; the
+                        # members below carve the *decoded* states, so their
+                        # estimates reflect exactly what crossed the wire
+                        stats, comm = self._codec_ship(grp, "shared", stats)
+                    else:
+                        # analytic, host-side: avoid syncing on the device pass
+                        comm = self._analytic_comm(fused, lat.shape[0])
+                    per_member = []
+                    for reg in members:
+                        kinds_map = reg.plan.column_kind_map
+                        # carve this query's columns *and* accumulator kinds
+                        # out of the shared pass's union states
+                        carved = {
+                            c: {k: stats[c][k] for k in kinds_map[c]}
+                            for c in reg.plan.columns
+                        }
+                        per_member.append(
+                            (carved, n_sampled, n_valid, n_overflow, n_truncated, comm)
+                        )
+                comm_total += comm
+                self.total_passes += 1
+                for reg, (stats_m, n_s, n_v, n_o, n_t, comm_m) in zip(members, per_member):
+                    reg.ring.append(
+                        _Pane(
+                            stats=stats_m,
+                            n_sampled=n_s,
+                            n_valid=n_v,
+                            n_overflow=n_o,
+                            n_truncated=n_t,
+                            n_dropped=n_dropped,
+                            comm_bytes=comm_m,
+                        )
                     )
-                )
-                del reg.ring[: -reg.window.size]
-                reg.panes_seen += 1
-                reg.pending_comm += comm_m
-                reg.downstream_tuples = reg.downstream_tuples + n_s
-                if reg.panes_seen % reg.window.stride == 0:
-                    due.append(reg)
-        singles, batches = self._emit_due(due, key, emitted)
-        for reg in due:  # emitted windows consumed their newly-shipped bytes
-            reg.pending_comm = 0
-        self._update_controllers(singles, batches)
+                    del reg.ring[: -reg.window.size]
+                    reg.panes_seen += 1
+                    reg.pending_comm += comm_m
+                    reg.downstream_tuples = reg.downstream_tuples + n_s
+                    if reg.panes_seen % reg.window.stride == 0:
+                        due.append(reg)
+        with spans.span("session.emit", pane=self.pane_index):
+            singles, batches = self._emit_due(due, key, emitted)
+            for reg in due:  # emitted windows consumed their newly-shipped bytes
+                reg.pending_comm = 0
+            self._update_controllers(singles, batches)
         self.pane_index += 1
         self.total_comm_bytes += comm_total
         self.total_dropped += n_dropped
@@ -933,19 +948,21 @@ class StreamSession:
             active_rows.extend(b_rows)
         if not active_rows:
             return
-        regs = list(self._regs.values())
-        state = feedback.stack_states(
-            (r.fraction, r.re_ema, r.steps) for r in regs
-        )
-        re_obs, n_obs = feedback.scatter_observations(len(regs), segments)
-        active = [False] * len(regs)
-        for i in active_rows:
-            active[i] = True
-        new = feedback.update_vector(state, re_obs, n_obs, slo_stack, jnp.asarray(active))
-        frac = jax.device_get(new.fraction)
-        ema = jax.device_get(new.re_ema)
-        for i, reg in enumerate(regs):
-            if active[i]:
-                reg.fraction = float(frac[i])
-                reg.re_ema = float(ema[i])
-                reg.steps += 1
+        with spans.span("session.controllers", pane=self.pane_index):
+            regs = list(self._regs.values())
+            state = feedback.stack_states(
+                (r.fraction, r.re_ema, r.steps) for r in regs
+            )
+            re_obs, n_obs = feedback.scatter_observations(len(regs), segments)
+            active = [False] * len(regs)
+            for i in active_rows:
+                active[i] = True
+            new = feedback.update_vector(state, re_obs, n_obs, slo_stack, jnp.asarray(active))
+            frac = jax.device_get(new.fraction)
+            ema = jax.device_get(new.re_ema)
+            spans.count("session.host_syncs", 2)
+            for i, reg in enumerate(regs):
+                if active[i]:
+                    reg.fraction = float(frac[i])
+                    reg.re_ema = float(ema[i])
+                    reg.steps += 1

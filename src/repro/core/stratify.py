@@ -75,13 +75,14 @@ class StratumTable:
         """
         from ..kernels.platform import on_tpu
 
-        if backend == "pallas" and on_tpu():
-            from ..kernels.geohash import geohash_encode
+        with jax.named_scope("stratify"):
+            if backend == "pallas" and on_tpu():
+                from ..kernels.geohash import geohash_encode
 
-            codes = geohash_encode(lat, lon, self.precision)
-        else:
-            codes = geohash.encode(lat, lon, self.precision)
-        return self.lookup(codes)
+                codes = geohash_encode(lat, lon, self.precision)
+            else:
+                codes = geohash.encode(lat, lon, self.precision)
+            return self.lookup(codes)
 
     def neighborhood_of(self, stratum_idx: jnp.ndarray) -> jnp.ndarray:
         """O(1) gather: stratum index -> neighborhood id."""

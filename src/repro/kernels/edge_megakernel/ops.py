@@ -65,21 +65,22 @@ def edge_megakernel(
     ``ext_idx``/``sk_idx`` select the value columns that also get extrema
     / sketch stat rows.
     """
-    ext_idx, sk_idx = tuple(ext_idx), tuple(sk_idx)
-    if interpret is None:
-        if not on_tpu():
-            return _edge_megakernel_segment(
-                vals, ok, scores, thresholds, num_slots,
-                sidx=sidx, lat=lat, lon=lon, codes=codes, precision=precision,
-                ext_idx=ext_idx, sk_idx=sk_idx,
-            )
-        interpret = False
-    return edge_megakernel_pallas(
-        vals, ok, scores, thresholds, num_slots,
-        sidx=sidx, lat=lat, lon=lon, codes=codes, precision=precision,
-        ext_idx=ext_idx, sk_idx=sk_idx,
-        n_block=n_block, s_block=s_block, interpret=interpret,
-    )
+    with jax.named_scope("reduce"):
+        ext_idx, sk_idx = tuple(ext_idx), tuple(sk_idx)
+        if interpret is None:
+            if not on_tpu():
+                return _edge_megakernel_segment(
+                    vals, ok, scores, thresholds, num_slots,
+                    sidx=sidx, lat=lat, lon=lon, codes=codes, precision=precision,
+                    ext_idx=ext_idx, sk_idx=sk_idx,
+                )
+            interpret = False
+        return edge_megakernel_pallas(
+            vals, ok, scores, thresholds, num_slots,
+            sidx=sidx, lat=lat, lon=lon, codes=codes, precision=precision,
+            ext_idx=ext_idx, sk_idx=sk_idx,
+            n_block=n_block, s_block=s_block, interpret=interpret,
+        )
 
 
 @functools.partial(

@@ -1,8 +1,9 @@
 """Jit'd public wrapper for the geohash kernel.
 
 Falls back to interpret mode automatically off-TPU so the same call site
-works everywhere; neighborhood/stratum lookup stays outside the kernel
-(vectorized searchsorted — dynamic VMEM gathers are not TPU-friendly).
+works everywhere; the stratum lookup stays outside the kernel
+(``StratumTable.lookup``: one XLA gather into the table's dense cell grid —
+dynamic VMEM gathers are not TPU-friendly).
 """
 
 from __future__ import annotations

@@ -571,10 +571,14 @@ class StreamSession:
         panes = reg.ring
         if len(panes) == 1:
             return panes[0].stats
-        stacked = jax.tree.map(
-            lambda *xs: jnp.stack(xs, axis=0), *[p.stats for p in panes]
-        )
-        spans.count("session.host_stacked_leaves", len(jax.tree.leaves(stacked)))
+        with spans.span("session.stack", pane=self.pane_index):
+            stacked = jax.tree.map(
+                lambda *xs: jnp.stack(xs, axis=0), *[p.stats for p in panes]
+            )
+        leaves = jax.tree.leaves(stacked)
+        spans.count("session.host_stacked_leaves", len(leaves))
+        # from shapes and dtypes: reading ``nbytes`` waits on no device work
+        spans.count("session.stacked_bytes", sum(x.nbytes for x in leaves))
         return stacked
 
     def _emit(self, reg: Registration, key) -> QueryResult:
@@ -584,7 +588,9 @@ class StreamSession:
         one-pane window finalizes with the same key as the shared pass, so
         session bounds are bit-identical to an independent ``execute``."""
         fn = self.pipe.finalize_fn(reg.plan, len(reg.ring))
-        estimates, stats = fn(self._window_stats(reg), key)
+        window_stats = self._window_stats(reg)
+        with spans.span("session.finalize", pane=self.pane_index):
+            estimates, stats = fn(window_stats, key)
         spans.count("session.finalize_dispatches")
         n_sampled, n_valid, n_overflow, n_truncated, comm, dropped = (
             self._window_counters(reg)
@@ -615,7 +621,8 @@ class StreamSession:
         b_pad = 1 << (b - 1).bit_length()
         member_stats = member_stats + [member_stats[0]] * (b_pad - b)
         fn = self.pipe.batched_finalize_fn(regs[0].plan, num_panes, b_pad)
-        estimates, stats = fn(member_stats, key)
+        with spans.span("session.finalize", pane=self.pane_index):
+            estimates, stats = fn(member_stats, key)
         spans.count("session.finalize_dispatches")
         counters = tuple(self._window_counters(reg) for reg in regs)
         return _EmitBatch(

@@ -171,13 +171,17 @@ def test_runtime_records_spans_and_counters_per_pane(table, panes):
         "runtime.dispatch": n,
         "session.pass": 2 * n,  # groups x panes
         "session.emit": n,
+        "session.stack": n - 1,  # the sliding ring, from its second pane on
+        "session.finalize": 2 * n,
         "runtime.retire": n,
     }
     # the sliding query's finalize and one batched finalize for the pair
     assert c["session.finalize_dispatches"] == 2 * n
     # the ring is stacked on the host from its second pane on, leaf by leaf
-    leaves = len(jax.tree.leaves(slide.ring[0].stats))
-    assert c["session.host_stacked_leaves"] == (n - 1) * leaves
+    leaves = jax.tree.leaves(slide.ring[0].stats)
+    assert c["session.host_stacked_leaves"] == (n - 1) * len(leaves)
+    pane_bytes = sum(x.nbytes for x in leaves)
+    assert c["session.stacked_bytes"] == sum(min(k, 4) for k in range(2, n + 1)) * pane_bytes
     assert "session.host_syncs" not in c  # no SLO, no codec: nothing blocks
     for s in st.spans.values():
         assert 0.0 <= s["self_s"] <= s["total_s"]
@@ -190,8 +194,9 @@ def test_runtime_records_spans_and_counters_per_pane(table, panes):
 
 def test_compiles_counted_under_emit_until_ring_is_warm(table, panes):
     """A fresh pipeline compiles a finalize for each new ring length of the
-    sliding query (1..4), under ``session.emit``; once the ring is full the
-    same programs serve and nothing compiles."""
+    sliding query (1..4), under ``session.emit`` (in its ``session.finalize``
+    child, the innermost span open); once the ring is full the same
+    programs serve and nothing compiles."""
     sess, _ = _session(EdgeCloudPipeline(table, PipelineConfig()))
     rt = StreamRuntime(sess, key=jax.random.key(1), config=RuntimeConfig(policy="block"))
     per_pane, prev = [], {}
@@ -201,11 +206,68 @@ def test_compiles_counted_under_emit_until_ring_is_warm(table, panes):
         now = rt.stats().counters
         per_pane.append({k: v - prev.get(k, 0) for k, v in now.items()})
         prev = now
-    emit = [d.get("compiles.session.emit", 0) for d in per_pane]
+    emit = [sum(d.get(f"compiles.session.{n}", 0) for n in ("emit", "stack", "finalize"))
+            for d in per_pane]
     assert all(e > 0 for e in emit[:4]), emit
+    assert all(d.get("compiles.session.finalize", 0) > 0 for d in per_pane[:4]), per_pane
     assert emit[4:] == [0] * (len(panes) - 4), emit
     assert all(k.startswith(("compiles.", "cache_loads.", "session.", "runtime."))
                for k in prev)
+
+
+def test_stack_and_finalize_spans_nest_inside_emit(table, panes):
+    """``session.stack`` and ``session.finalize`` are the emit's children:
+    the emit's self time is its total less theirs, neither holds a span of
+    its own, and there is one ``session.finalize`` a finalize dispatch."""
+    sess, _ = _session(EdgeCloudPipeline(table, PipelineConfig()))
+    rt = StreamRuntime(sess, key=jax.random.key(4), config=RuntimeConfig(policy="block"))
+    rt.run(panes)
+    st = rt.stats()
+    emit, stack, fin = (st.spans[f"session.{n}"] for n in ("emit", "stack", "finalize"))
+    assert emit["self_s"] == pytest.approx(emit["total_s"] - stack["total_s"] - fin["total_s"])
+    for s in (stack, fin):
+        assert s["self_s"] == s["total_s"] and s["total_s"] <= emit["total_s"]
+    assert fin["count"] == st.counters["session.finalize_dispatches"]
+
+
+def test_stacked_bytes_of_a_geohash6_ring(panes):
+    """At Geohash-6 a pane's sketch state is ``(6558, 513)`` float32 rows per
+    sketched column; the counter adds every stacked leaf's bytes, from the
+    shapes alone, so the pane whose ring holds four panes adds four panes'
+    state."""
+    table6 = make_table(*SHENZHEN_BBOX, precision=6)
+    assert table6.num_slots == 6558
+    sess = StreamSession(EdgeCloudPipeline(table6, PipelineConfig()))
+    slide = sess.register(Q_SLIDE, window=WindowSpec("sliding", size=4), initial_fraction=1.0)
+    rt = StreamRuntime(sess, key=jax.random.key(5), config=RuntimeConfig(policy="block"))
+    added, prev = [], 0
+    for p in panes[:4]:
+        rt.offer(p)
+        rt.process()
+        now = rt.stats().counters.get("session.stacked_bytes", 0)
+        added.append(now - prev)
+        prev = now
+    leaves = jax.tree.leaves(slide.ring[0].stats)
+    assert (6558, 513) in [x.shape for x in leaves]
+    stacked = jax.tree.leaves(sess._window_stats(slide))
+    assert (4, 6558, 513) in [x.shape for x in stacked]
+    assert added == [0] + [k * sum(x.nbytes for x in leaves) for k in (2, 3, 4)]
+    assert added[3] == sum(x.nbytes for x in stacked) > 4 * 6558 * 513 * 4
+
+
+def test_session_step_without_a_runtime_records_nothing(table, panes):
+    """Stepping a session by hand, after a runtime has run it, adds nothing
+    to that runtime's spans or counters."""
+    sess, _ = _session(EdgeCloudPipeline(table, PipelineConfig()))
+    rt = StreamRuntime(sess, key=jax.random.key(6), config=RuntimeConfig(policy="block"))
+    rt.run(panes[:4])
+    before = rt.stats()
+    for i, p in enumerate(panes[4:]):
+        sess.step(jax.random.key(i), p)
+    after = rt.stats()
+    assert sess.pane_index == len(panes)
+    assert after.spans == before.spans and after.counters == before.counters
+    assert "session.stack" in before.spans and "session.stacked_bytes" in before.counters
 
 
 # -- the profiler's trace --------------------------------------------------------
@@ -234,7 +296,8 @@ def test_profiler_trace_holds_program_spans(table, panes, tmp_path):
                         ((plane.name, li), e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
                     )
     assert {"runtime.source", "runtime.queue_put", "runtime.queue_get", "runtime.stage",
-            "runtime.dispatch", "runtime.retire", "session.pass", "session.emit"} <= set(named)
+            "runtime.dispatch", "runtime.retire", "session.pass", "session.emit",
+            "session.stack", "session.finalize"} <= set(named)
     assert not HARNESS_SPANS & set(named)
     assert sorted(s["pane"] for *_, s in named["runtime.dispatch"]) == [2, 3]
     passes = named["session.pass"]
@@ -246,6 +309,10 @@ def test_profiler_trace_holds_program_spans(table, panes, tmp_path):
         outer = [d for d in named["runtime.dispatch"] if d[3]["pane"] == s["pane"]]
         assert len(outer) == 1
         assert outer[0][0] == line and outer[0][1] <= a and b <= outer[0][2]
+    for name in ("session.stack", "session.finalize"):
+        for line, a, b, s in named[name]:
+            (outer,) = [e for e in named["session.emit"] if e[3]["pane"] == s["pane"]]
+            assert outer[0] == line and outer[1] <= a and b <= outer[2]
 
 
 # -- program names and layer scopes ----------------------------------------------

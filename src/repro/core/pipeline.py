@@ -55,7 +55,8 @@ Entry points:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import operator
+from functools import partial, reduce
 from typing import Mapping, NamedTuple
 
 import jax
@@ -680,6 +681,12 @@ def _result_template(plan: Plan) -> QueryResult:
     )
 
 
+def _window_counts(counts: tuple) -> tuple:
+    """A window's ``(n_sampled, n_valid, n_overflow, n_truncated)``: each
+    pane's counters summed in ring order (one pane passes through)."""
+    return tuple(reduce(operator.add, xs) for xs in zip(*counts))
+
+
 class EdgeCloudPipeline:
     """Single-program query engine; optionally distributed over mesh axes."""
 
@@ -847,24 +854,23 @@ class EdgeCloudPipeline:
         return fn
 
     def _finalize_body(self, plan: Plan, num_panes: int):
-        """``(stats, key) -> (estimates, merged)`` for one query's window:
-        merge ``num_panes`` stacked pane accumulators (pass-through when the
-        window is one pane, preserving bit-compatibility with ``execute``)
-        and finalize."""
+        """``(ring, key) -> (estimates, merged)`` for one query's window:
+        ``ring`` is the window's ``num_panes`` pane stats, unstacked, as the
+        session's ring holds them.  The panes are stacked on a leading axis
+        and merged here, inside the program, so an emit hands the ring over
+        without a per-leaf stack on the host; one pane is a pass-through
+        (bit-compatible with ``execute``)."""
         table = self.table
 
-        if num_panes == 1:
-
-            def finalize(stats, bkey):
-                return aqp.finalize(plan, table, stats, key=bkey), stats
-
-        else:
-
-            def finalize(stacked, bkey):
+        def finalize(ring, bkey):
+            if num_panes == 1:
+                (merged,) = ring
+            else:
+                stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *ring)
                 merged = {
                     c: estimators.merge_accs_panes(stacked[c]) for c in plan.columns
                 }
-                return aqp.finalize(plan, table, merged, key=bkey), merged
+            return aqp.finalize(plan, table, merged, key=bkey), merged
 
         return finalize
 
@@ -872,25 +878,39 @@ class EdgeCloudPipeline:
         """Jitted cloud-side emit for one registration, cached by *finalize
         signature*: queries that differ only in sampling method / mode /
         ROI share one compiled program (finalize never reads those — see
-        :func:`~.query.finalize_signature`)."""
+        :func:`~.query.finalize_signature`).
+
+        ``(ring, counts, key) -> (estimates, merged, window_counts)``:
+        ``ring`` is the window's ``num_panes`` pane stats and ``counts`` each
+        pane's ``(n_sampled, n_valid, n_overflow, n_truncated)``; the window's
+        four counters are their sums, taken in the program too, so an emit
+        dispatches this one program and no eager op."""
         key = ("single", aqp.finalize_signature(plan), num_panes)
         fn = self._finalizers.get(key)
         self._cache_event("finalize", fn is not None)
         if fn is not None:
             return fn
-        fn = jax.jit(self._finalize_body(plan, num_panes))
+        body = self._finalize_body(plan, num_panes)
+
+        def finalize(ring, counts, bkey):
+            return (*body(ring, bkey), _window_counts(counts))
+
+        fn = jax.jit(finalize)
         self._finalizers[key] = fn
         return fn
 
     def batched_finalize_fn(self, plan: Plan, num_panes: int, batch: int):
         """Jitted *vmapped* finalize: one dispatch emits ``batch`` queries
         sharing a finalize signature (key broadcast — each row computes
-        exactly what its singleton finalize would, so batching preserves
-        bit-parity).  Takes the *list* of ``batch`` member window-stats
-        pytrees; the leading-axis stack happens inside the compiled
-        program — stacking op-by-op on the host costs one dispatch per
-        leaf per batch, which is exactly the per-query overhead batching
-        exists to amortize.  ``batch`` is the padded width (sessions pad
+        what its singleton finalize would: counts and extrema exactly, float
+        sums up to the order XLA gives a reduction under ``vmap``, which
+        moves the ``var`` bootstrap's bound by an ulp at Geohash-6).  Takes
+        the lists of ``batch`` members' rings and pane counts, each as
+        :meth:`finalize_fn` takes one; the member axis and the pane axis are
+        both stacked inside the compiled program, so the host hands the
+        rings over as they are and dispatches nothing per leaf.  Returns
+        estimates and merged stats with a leading member axis and each
+        member's window counts.  ``batch`` is the padded width (sessions pad
         to the next power of two so tenant churn steps through O(log Q)
         compiled widths, not one per group size)."""
         key = ("batched", aqp.finalize_signature(plan), num_panes, batch)
@@ -900,9 +920,9 @@ class EdgeCloudPipeline:
             return fn
         body = jax.vmap(self._finalize_body(plan, num_panes), in_axes=(0, None))
 
-        def finalize_batched(member_stats, bkey):
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *member_stats)
-            return body(stacked, bkey)
+        def finalize_batched(member_rings, member_counts, bkey):
+            rings = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *member_rings)
+            return (*body(rings, bkey), tuple(_window_counts(c) for c in member_counts))
 
         fn = jax.jit(finalize_batched)
         self._finalizers[key] = fn
@@ -934,7 +954,8 @@ class EdgeCloudPipeline:
         the analytic dense model.  One-shot executes open a fresh stream, so
         a delta codec degenerates to a keyframe here."""
         stats, nbytes = wirecodec.roundtrip(self.codec_spec.for_stream(), res.stats)
-        estimates, stats = self.finalize_fn(plan, 1)(stats, key)
+        counts = ((res.n_sampled, res.n_valid, res.n_overflow, res.n_truncated),)
+        estimates, stats, _ = self.finalize_fn(plan, 1)((stats,), counts, key)
         return res._replace(estimates=estimates, stats=stats, comm_bytes=nbytes)
 
     def execute(self, query: Query, key, window, fraction=1.0) -> QueryResult:

@@ -26,7 +26,8 @@ approximate analytics wins by sharing one sampling pass):
     confidence, column layout; ROI/method/mode drop out) are stacked on a
     leading axis and carved out of the shared merged ``ColumnStats`` by one
     jitted ``vmap`` dispatch per signature — one compiled program per
-    signature, not per query, with bit-parity to the per-query path.
+    signature, not per query, matching the per-query path (counts and
+    extrema exactly; see ``EdgeCloudPipeline.batched_finalize_fn``).
     ``step.results`` materializes per-query views lazily on access, so a
     pane serving thousands of registered dashboards pays O(signatures)
     dispatches, and only the results actually read pay slicing.
@@ -538,9 +539,8 @@ class StreamSession:
             spans.count("session.host_syncs")
             return wirecodec.roundtrip(stream, stats)
 
-    def _window_counters(self, reg: Registration) -> tuple:
-        """This query's window-level counters, summed over its pane ring
-        (device-lazy adds; host ints for the byte/drop accounting).
+    def _window_host_counters(self, reg: Registration) -> tuple:
+        """This query's window-level host ints ``(comm, dropped)``.
 
         ``comm`` is the bytes *newly shipped* for this query since its
         previous emit (``pending_comm``), not a re-sum of every pane in
@@ -550,51 +550,46 @@ class StreamSession:
         pane is new).  Read non-destructively — ``emit_all``'s serving
         reads must not consume the counter; ``step`` resets it only after
         a scheduled emit."""
-        panes = reg.ring
-        n_sampled = panes[0].n_sampled
-        n_valid = panes[0].n_valid
-        n_overflow = panes[0].n_overflow
-        n_truncated = panes[0].n_truncated
-        for p in panes[1:]:
-            n_sampled = n_sampled + p.n_sampled
-            n_valid = n_valid + p.n_valid
-            n_overflow = n_overflow + p.n_overflow
-            n_truncated = n_truncated + p.n_truncated
-        comm = reg.pending_comm
-        dropped = sum(p.n_dropped for p in panes)
-        return (n_sampled, n_valid, n_overflow, n_truncated, comm, dropped)
+        return reg.pending_comm, sum(p.n_dropped for p in reg.ring)
 
-    def _window_stats(self, reg: Registration):
-        """The ring's stats, stacked on a leading pane axis when the window
-        spans multiple panes (pass-through for one pane, preserving
-        bit-compatibility with ``execute``)."""
+    def _window_ring(self, reg: Registration) -> tuple:
+        """The ring as the finalize program takes it: the panes' stats and
+        their ``(n_sampled, n_valid, n_overflow, n_truncated)``, unstacked.
+        A multi-pane ring is stacked, merged and its counters summed inside
+        the program (see ``EdgeCloudPipeline.finalize_fn``), so the host
+        dispatches no per-leaf stack or counter add; a one-pane ring passes
+        through, preserving bit-compatibility with ``execute``."""
         panes = reg.ring
         if len(panes) == 1:
-            return panes[0].stats
+            (p,) = panes
+            return (p.stats,), ((p.n_sampled, p.n_valid, p.n_overflow, p.n_truncated),)
         with spans.span("session.stack", pane=self.pane_index):
-            stacked = jax.tree.map(
-                lambda *xs: jnp.stack(xs, axis=0), *[p.stats for p in panes]
+            ring = tuple(p.stats for p in panes)
+            counts = tuple(
+                (p.n_sampled, p.n_valid, p.n_overflow, p.n_truncated) for p in panes
             )
-        leaves = jax.tree.leaves(stacked)
-        spans.count("session.host_stacked_leaves", len(leaves))
+        leaves = jax.tree.leaves(ring)
+        spans.count("session.program_stacked_windows")
+        spans.count("session.host_stacked_leaves", len(leaves) // len(panes))
         # from shapes and dtypes: reading ``nbytes`` waits on no device work
         spans.count("session.stacked_bytes", sum(x.nbytes for x in leaves))
-        return stacked
+        return ring, counts
 
     def _emit(self, reg: Registration, key) -> QueryResult:
-        """Assemble this query's window from its pane ring and finalize.
+        """Finalize this query's window from its pane ring: one program
+        assembles the window and finalizes it.
 
         ``key`` (the step key) seeds the bootstrap error bounds: a
         one-pane window finalizes with the same key as the shared pass, so
         session bounds are bit-identical to an independent ``execute``."""
         fn = self.pipe.finalize_fn(reg.plan, len(reg.ring))
-        window_stats = self._window_stats(reg)
+        ring, counts = self._window_ring(reg)
         with spans.span("session.finalize", pane=self.pane_index):
-            estimates, stats = fn(window_stats, key)
+            estimates, stats, (n_sampled, n_valid, n_overflow, n_truncated) = fn(
+                ring, counts, key
+            )
         spans.count("session.finalize_dispatches")
-        n_sampled, n_valid, n_overflow, n_truncated, comm, dropped = (
-            self._window_counters(reg)
-        )
+        comm, dropped = self._window_host_counters(reg)
         return QueryResult(
             estimates=estimates,
             stats=stats,
@@ -603,7 +598,7 @@ class StreamSession:
             n_overflow=n_overflow,
             n_truncated=n_truncated,
             # uplink newly spent since this query's previous emit (host
-            # int — exact past 2^31; see _window_counters)
+            # int — exact past 2^31; see _window_host_counters)
             comm_bytes=comm,
             # window-level drop accounting: tuples the window's panes shed
             # upstream (survives checkpoint/restore — the ring carries it)
@@ -611,20 +606,23 @@ class StreamSession:
         )
 
     def _emit_batch(self, regs: list, num_panes: int, key) -> _EmitBatch:
-        """One vmapped finalize over a finalize-signature batch: member
-        window stats stacked on a leading axis *inside* the jitted program
-        (padded to the next power of two by repeating row 0, so churning
-        batch widths step through O(log Q) compiled programs), key
-        broadcast — each row computes exactly its singleton finalize."""
-        member_stats = [self._window_stats(reg) for reg in regs]
-        b = len(regs)
-        b_pad = 1 << (b - 1).bit_length()
-        member_stats = member_stats + [member_stats[0]] * (b_pad - b)
-        fn = self.pipe.batched_finalize_fn(regs[0].plan, num_panes, b_pad)
+        """One vmapped finalize over a finalize-signature batch: members'
+        rings handed over unstacked, member and pane axes stacked *inside*
+        the jitted program (padded to the next power of two by repeating
+        row 0, so churning batch widths step through O(log Q) compiled
+        programs), key broadcast — each row computes its singleton
+        finalize."""
+        rings, counts = zip(*(self._window_ring(reg) for reg in regs))
+        pad = (1 << (len(regs) - 1).bit_length()) - len(regs)
+        fn = self.pipe.batched_finalize_fn(regs[0].plan, num_panes, len(regs) + pad)
         with spans.span("session.finalize", pane=self.pane_index):
-            estimates, stats = fn(member_stats, key)
+            estimates, stats, window_counts = fn(
+                list(rings) + [rings[0]] * pad, list(counts) + [counts[0]] * pad, key
+            )
         spans.count("session.finalize_dispatches")
-        counters = tuple(self._window_counters(reg) for reg in regs)
+        counters = tuple(
+            (*c, *self._window_host_counters(reg)) for reg, c in zip(regs, window_counts)
+        )
         return _EmitBatch(
             regs=tuple(regs), estimates=estimates, stats=stats, counters=counters
         )

@@ -177,7 +177,9 @@ def test_runtime_records_spans_and_counters_per_pane(table, panes):
     }
     # the sliding query's finalize and one batched finalize for the pair
     assert c["session.finalize_dispatches"] == 2 * n
-    # the ring is stacked on the host from its second pane on, leaf by leaf
+    # from its second pane on the ring goes to the finalize program unstacked,
+    # and the program stacks it, leaf by leaf
+    assert c["session.program_stacked_windows"] == counts["session.stack"] == n - 1
     leaves = jax.tree.leaves(slide.ring[0].stats)
     assert c["session.host_stacked_leaves"] == (n - 1) * len(leaves)
     pane_bytes = sum(x.nbytes for x in leaves)
@@ -237,7 +239,8 @@ def test_stacked_bytes_of_a_geohash6_ring(panes):
     state."""
     table6 = make_table(*SHENZHEN_BBOX, precision=6)
     assert table6.num_slots == 6558
-    sess = StreamSession(EdgeCloudPipeline(table6, PipelineConfig()))
+    pipe = EdgeCloudPipeline(table6, PipelineConfig())
+    sess = StreamSession(pipe)
     slide = sess.register(Q_SLIDE, window=WindowSpec("sliding", size=4), initial_fraction=1.0)
     rt = StreamRuntime(sess, key=jax.random.key(5), config=RuntimeConfig(policy="block"))
     added, prev = [], 0
@@ -249,8 +252,11 @@ def test_stacked_bytes_of_a_geohash6_ring(panes):
         prev = now
     leaves = jax.tree.leaves(slide.ring[0].stats)
     assert (6558, 513) in [x.shape for x in leaves]
-    stacked = jax.tree.leaves(sess._window_stats(slide))
-    assert (4, 6558, 513) in [x.shape for x in stacked]
+    ring, counts = sess._window_ring(slide)
+    program = pipe.finalize_fn(slide.plan, 4).lower(ring, counts, jax.random.key(5)).as_text()
+    assert "tensor<4x6558x513xf32>" in program  # the program stacks the sketch ring
+    stacked = jax.tree.leaves(ring)
+    assert [x.shape for x in stacked].count((6558, 513)) >= 4
     assert added == [0] + [k * sum(x.nbytes for x in leaves) for k in (2, 3, 4)]
     assert added[3] == sum(x.nbytes for x in stacked) > 4 * 6558 * 513 * 4
 
@@ -350,15 +356,15 @@ def test_programs_carry_names_and_layer_scopes(table, panes):
     for scope in ("stratify", "sample", "reduce"):
         assert _has_scope(text, "refined_pass", scope), scope
 
-    ring = sess._window_stats(slide)
-    text = _hlo(pipe.finalize_fn(slide.plan, 4), ring, key)
+    ring, counts = sess._window_ring(slide)
+    text = _hlo(pipe.finalize_fn(slide.plan, 4), ring, counts, key)
     assert text.startswith("HloModule jit_finalize,")
     for scope in ("merge_panes", "bounds"):
         assert _has_scope(text, "finalize", scope), scope
 
     pair = [r for r in sess.registrations if r is not slide]
-    stats = [r.ring[0].stats for r in pair]
-    text = _hlo(pipe.batched_finalize_fn(pair[0].plan, 1, 2), stats, key)
+    rings, counts = zip(*(sess._window_ring(r) for r in pair))
+    text = _hlo(pipe.batched_finalize_fn(pair[0].plan, 1, 2), list(rings), list(counts), key)
     assert text.startswith("HloModule jit_finalize_batched")
 
     text = _hlo(pipe._query_fn(Q_SLIDE, sharded=False), key, lat, lon, cols, valid,

@@ -18,8 +18,6 @@ from repro.kernels.edge_megakernel.ref import edge_megakernel_ref
 from repro.kernels.edge_reduce import edge_reduce
 from repro.kernels.edge_reduce.ops import edge_reduce_percol
 from repro.kernels.edge_reduce.ref import edge_reduce_ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.geohash import geohash_encode
 from repro.kernels.geohash.ref import encode_ref
 from repro.kernels.sample_mask import sample_mask
@@ -84,16 +82,6 @@ def run():
         f"traversal_ratio={mk['megakernel_traversal_ratio']:.2f}x;"
         f"parity={mk['megakernel_parity']};backend={jax.default_backend()}"))
 
-    B, S, H, K, dh = 1, 512, 8, 2, 64
-    q = jnp.asarray(rng.normal(0, 1, (B, S, H, dh)), jnp.bfloat16)
-    k = jnp.asarray(rng.normal(0, 1, (B, S, K, dh)), jnp.bfloat16)
-    v = jnp.asarray(rng.normal(0, 1, (B, S, K, dh)), jnp.bfloat16)
-    ref_us = time_call(flash_attention_ref, q, k, v)
-    o = flash_attention(q, k, v)
-    r = flash_attention_ref(q, k, v)
-    err = float(jnp.max(jnp.abs(o.astype(jnp.float32) - r.astype(jnp.float32))))
-    lines.append(csv_line("kernel_flash_attention_ref", ref_us,
-                          f"S={S};H={H};K={K};max_err={err:.4f};backend={jax.default_backend()}"))
     return lines
 
 

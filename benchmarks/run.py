@@ -11,7 +11,6 @@ Prints ``name,us_per_call,derived`` CSV lines.
   kernels    -> kernel_bench
   query API  -> query_bench (grouped 3-aggregate query vs legacy path)
   serving    -> multitenant_bench (Q-tenant batched finalize + churn)
-  §Roofline  -> roofline (reads experiments/dryrun artifacts)
 """
 
 from __future__ import annotations
@@ -30,7 +29,6 @@ def main() -> None:
         kernel_bench,
         multitenant_bench,
         query_bench,
-        roofline,
         uplink_codec_bench,
     )
 
@@ -44,7 +42,6 @@ def main() -> None:
         ("query_bench", query_bench),
         ("multitenant_bench", multitenant_bench),
         ("uplink_codec_bench", uplink_codec_bench),
-        ("roofline", roofline),
     ]
     args = sys.argv[1:]
     dry = "--dry" in args

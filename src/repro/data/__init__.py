@@ -1,13 +1,11 @@
-"""Data substrate: synthetic geo-referenced streams + LM token streams."""
+"""Data substrate: synthetic geo-referenced streams and paced/bursty sources."""
 
 from .sources import BurstySource, PacedSource
 from .streams import chicago_aq_stream, shenzhen_taxi_stream
-from .tokens import StratifiedTokenStream
 
 __all__ = [
     "BurstySource",
     "PacedSource",
     "chicago_aq_stream",
     "shenzhen_taxi_stream",
-    "StratifiedTokenStream",
 ]

@@ -18,7 +18,6 @@ hot loop.  On TPU those become:
                     sampling + moments/extrema/sketch stat rows in ONE
                     pass — the hot path behind
                     ``PipelineConfig(backend="fused")``
-  flash_attention/  blocked causal attention for the LM serving substrate
 
 Every kernel has ops.py (jit'd wrapper with an interpret switch) and
 ref.py (pure-jnp oracle); tests sweep shapes/dtypes in interpret mode and
@@ -29,7 +28,6 @@ assert allclose against the oracle.  Block tilings live in tiling.py
 from . import (
     edge_megakernel,
     edge_reduce,
-    flash_attention,
     geohash,
     sample_mask,
     stratified_stats,
@@ -39,7 +37,6 @@ from . import (
 __all__ = [
     "edge_megakernel",
     "edge_reduce",
-    "flash_attention",
     "geohash",
     "sample_mask",
     "stratified_stats",

@@ -1,25 +1,5 @@
-"""Sharding substrate: logical axis rules -> mesh PartitionSpecs."""
+"""Sharding substrate: mesh construction and the mesh-axis vocabulary."""
 
-from .logical import (
-    MESH_AXIS_NAMES,
-    LogicalRules,
-    activation_rules,
-    active_rules,
-    constrain,
-    default_rules,
-    param_sharding,
-    spec_for,
-    use_rules,
-)
+from .compat import MESH_AXIS_NAMES, compat_make_mesh
 
-__all__ = [
-    "LogicalRules",
-    "MESH_AXIS_NAMES",
-    "activation_rules",
-    "active_rules",
-    "constrain",
-    "default_rules",
-    "param_sharding",
-    "spec_for",
-    "use_rules",
-]
+__all__ = ["MESH_AXIS_NAMES", "compat_make_mesh"]

@@ -1,4 +1,4 @@
-"""Test config: CPU, single device (dry-run tests spawn subprocesses).
+"""Test config: CPU, single device (multi-device tests spawn subprocesses).
 
 Hypothesis profile selection (``HYPOTHESIS_PROFILE`` env var):
 

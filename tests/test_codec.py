@@ -620,7 +620,7 @@ from repro.core import (
     make_table, query as aqp, windows,
 )
 from repro.data.streams import shenzhen_taxi_stream
-from repro.launch.mesh import compat_make_mesh
+from repro.sharding.compat import compat_make_mesh
 
 assert jax.device_count() == 8
 mesh = compat_make_mesh((8,), ("data",))

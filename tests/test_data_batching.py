@@ -1,14 +1,10 @@
-"""EdgeSOS LM data plane: unbiased weighted loss + stream generators."""
+"""Stream generators: the chunk schema and the spatial skew of the mobility stream."""
 
 import numpy as np
-import pytest
 
-import jax
 import jax.numpy as jnp
 
-from repro.data.batching import edgesos_batch, full_batch
 from repro.data.streams import chicago_aq_stream, materialize, shenzhen_taxi_stream
-from repro.data.tokens import StratifiedTokenStream
 
 
 def test_stream_generators_schema():
@@ -34,28 +30,3 @@ def test_streams_spatially_skewed():
     assert top / nz.sum() > 0.3
     # and the median cell is far below the mean (long tail)
     assert np.median(nz) < 0.5 * nz.mean()
-
-
-def test_edgesos_batch_weights_unbiased():
-    stream = StratifiedTokenStream(vocab_size=128, seq_len=8, num_strata=8, seed=0)
-    window = next(iter(stream.batches(64, 1)))
-    full = full_batch(window, 8)
-    assert float(jnp.sum(full.seq_weight)) == pytest.approx(64.0)
-    # HT weights: E[sum of weights] == window size
-    sums = []
-    for t in range(50):
-        b = edgesos_batch(jax.random.key(t), window, 0.5, 8, out_batch=48)
-        sums.append(float(jnp.sum(b.seq_weight)))
-        assert b.tokens.shape == (48, 8)
-        kept = int(jnp.sum(b.seq_weight > 0))
-        assert kept <= 48
-    assert np.mean(sums) == pytest.approx(64.0, rel=0.05)
-
-
-def test_edgesos_batch_stratum_counts_are_window_population():
-    stream = StratifiedTokenStream(vocab_size=64, seq_len=4, num_strata=5, seed=1)
-    window = next(iter(stream.batches(32, 1)))
-    b = edgesos_batch(jax.random.key(0), window, 0.75, 5, out_batch=28)
-    assert int(jnp.sum(b.stratum_counts)) == 32
-    expected = np.bincount(window.stratum, minlength=6)
-    np.testing.assert_array_equal(np.asarray(b.stratum_counts), expected)

@@ -414,6 +414,15 @@ def test_edg005_fires_when_sharding_declares_no_vocabulary(tmp_path):
     )
 
 
+def test_edg005_real_tree_declares_axes_in_sharding_compat():
+    from tools.edgelint.framework import load_project
+    from tools.edgelint.rules.collectives import declared_axes
+
+    axes, where = declared_axes(load_project(REPO_ROOT, ["src"]))
+    assert where == "src/repro/sharding/compat.py"
+    assert axes == {"pod", "data", "model"}
+
+
 # ---------------------------------------------------------------------------
 # EDG006 — ref purity (oracles are jax-free, self-contained numpy)
 # ---------------------------------------------------------------------------

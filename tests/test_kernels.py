@@ -11,8 +11,6 @@ import jax.numpy as jnp
 from repro.kernels.edge_reduce.edge_reduce import edge_reduce_pallas
 from repro.kernels.edge_reduce.ops import edge_reduce_percol
 from repro.kernels.edge_reduce.ref import edge_reduce_ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.geohash import geohash_encode
 from repro.kernels.geohash.ref import encode_ref
 from repro.kernels.sample_mask import sample_mask
@@ -130,36 +128,3 @@ def test_edge_reduce_generalizes_stratified_stats(rng):
     np.testing.assert_allclose(np.asarray(cnt), np.asarray(r_cnt), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(s1[0]), np.asarray(r_s1), rtol=2e-6, atol=1e-3)
     np.testing.assert_allclose(np.asarray(s2[0]), np.asarray(r_s2), rtol=2e-6, atol=1e-2)
-
-
-@pytest.mark.parametrize(
-    "B,S,H,K,dh",
-    [
-        (1, 256, 4, 4, 64),  # MHA
-        (2, 512, 8, 2, 64),  # GQA
-        (1, 512, 8, 1, 128),  # MQA
-        (1, 256, 4, 4, 112),  # zamba head_dim (padded to 128 internally)
-        (1, 300, 4, 2, 64),  # ragged seq (padded internally)
-    ],
-)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_kernel(rng, B, S, H, K, dh, dtype):
-    q = jnp.asarray(rng.normal(0, 1, (B, S, H, dh)), dtype)
-    k = jnp.asarray(rng.normal(0, 1, (B, S, K, dh)), dtype)
-    v = jnp.asarray(rng.normal(0, 1, (B, S, K, dh)), dtype)
-    got = flash_attention(q, k, v).astype(jnp.float32)
-    ref = flash_attention_ref(q, k, v).astype(jnp.float32)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=tol, rtol=tol)
-
-
-def test_flash_attention_matches_model_layer(rng):
-    """Kernel agrees with the model's chunked-causal attention path."""
-    from repro.models.layers import chunked_causal_attention
-
-    q = jnp.asarray(rng.normal(0, 1, (2, 512, 8, 64)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (2, 512, 2, 64)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (2, 512, 2, 64)), jnp.float32)
-    a = flash_attention(q, k, v)
-    b = chunked_causal_attention(q, k, v, q_chunk=128)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5, rtol=3e-5)

@@ -33,9 +33,11 @@ Two aggregation modes mirror the paper's two edge->cloud transmission modes:
     combination rule is exact (parallel-variance / Chan et al. decomposition),
     so both modes return identical estimates — a property we test.
 
-Numerics: per-stratum second moments are computed *centered* (two-pass)
-inside a shard, and the cross-shard merge uses the mean-shift decomposition,
-avoiding the catastrophic cancellation of naive sum-of-squares in f32.
+Numerics: per-stratum second moments are computed *centered* inside a
+shard (Chan-et-al. merges along each stratum's run of a stratum-sorted
+pass, see :func:`accumulate_sorted`), and the cross-shard merge uses the
+mean-shift decomposition, avoiding the catastrophic cancellation of naive
+sum-of-squares in f32.
 """
 
 from __future__ import annotations
@@ -114,23 +116,13 @@ def sample_stats(
     num_slots: int,
     counts: jnp.ndarray | None = None,
 ) -> StratumStats:
-    """Per-stratum moments of the *sampled* tuples (eq 4), two-pass centered.
+    """Per-stratum moments of the *sampled* tuples (eq 4), centered.
 
-    ``counts`` are the population sizes N_k; when None they are recomputed
-    from ``stratum_idx`` (all tuples of the window, sampled or not).
+    One stratum-sorted pass (:func:`accumulate_sorted`).  ``counts`` are the
+    population sizes N_k; when None they are the tuples of each stratum in
+    ``stratum_idx`` (all tuples of the window, sampled or not).
     """
-    values = values.astype(jnp.float32)
-    m = mask.astype(jnp.float32)
-    if counts is None:
-        counts = jax.ops.segment_sum(
-            jnp.ones_like(stratum_idx, dtype=jnp.int32), stratum_idx, num_segments=num_slots
-        )
-    n = jax.ops.segment_sum(m, stratum_idx, num_segments=num_slots)
-    wsum = jax.ops.segment_sum(m * values, stratum_idx, num_segments=num_slots)
-    mean = jnp.where(n > 0, wsum / jnp.maximum(n, 1.0), 0.0)
-    centered = values - mean[stratum_idx]
-    m2 = jax.ops.segment_sum(m * centered * centered, stratum_idx, num_segments=num_slots)
-    return StratumStats(n=n, total=counts.astype(jnp.float32), wsum=wsum, m2=m2, mean=mean)
+    return MOMENTS.accumulate(values, stratum_idx, mask, num_slots, counts=counts)
 
 
 def merge_stats(a: StratumStats, b: StratumStats) -> StratumStats:
@@ -227,18 +219,19 @@ def column_stats(
 ) -> ColumnStats:
     """Per-stratum generalized accumulator of the sampled tuples of one column.
 
-    Moments come from :func:`sample_stats` (identical ops, so estimates built
-    from ``.base`` match the legacy path bit-for-bit); extrema are masked
-    segment min/max with ``±inf`` identities on empty strata.  Pass
-    ``extrema=False`` when no aggregate reads min/max — the fields are then
-    filled with their identities without running the segment reductions.
+    Moments are :func:`sample_stats`'; extrema are the masked per-stratum
+    min/max with ``±inf`` identities on empty strata, from the same sorted
+    pass.  Pass ``extrema=False`` when no aggregate reads min/max — the
+    fields are then filled with their identities without reducing them.
     """
-    base = sample_stats(values, stratum_idx, mask, num_slots, counts=counts)
-    ext = (
-        EXTREMA.accumulate(values, stratum_idx, mask, num_slots)
-        if extrema
-        else EXTREMA.identity(num_slots)
-    )
+    if extrema:
+        base, ext = accumulate_sorted(
+            ((MOMENTS, values, mask), (EXTREMA, values, mask)),
+            stratum_idx, num_slots, counts,
+        )
+    else:
+        base = sample_stats(values, stratum_idx, mask, num_slots, counts=counts)
+        ext = EXTREMA.identity(num_slots)
     return ColumnStats(
         n=base.n, total=base.total, wsum=base.wsum, m2=base.m2, mean=base.mean,
         min=ext.min, max=ext.max,
@@ -551,8 +544,31 @@ class Accumulator:
 
     kind: str = "?"
 
+    #: True where the kind implements the three sorted-pass hooks below:
+    #: the engine then reduces it inside the pass's one stratum-sorted scan
+    #: (:func:`accumulate_sorted`) instead of calling :meth:`accumulate`
+    sorted_pass: bool = False
+
     def accumulate(self, values, stratum_idx, mask, num_slots, counts=None):
         """Reduce one window's sampled tuples of a column to a state."""
+        raise NotImplementedError
+
+    def sorted_leaves(self, values, mask):
+        """Sorted-pass hook: the per-tuple summary pytree, ``(N,)`` leaves,
+        of one column's values and kept flags in stratum-sorted order."""
+        raise NotImplementedError
+
+    def sorted_combine(self, a, b):
+        """Sorted-pass hook: the summary of two adjacent runs of one stratum
+        (``a`` before ``b``); must be associative."""
+        raise NotImplementedError
+
+    def sorted_state(self, runs, present, counts):
+        """Sorted-pass hook: the state from each slot's summary over its
+        kept tuples (``(S+1,)`` leaves).  ``present`` is False where the
+        slot kept no tuple: its summary is then undefined and the state
+        takes the merge identity there.  ``counts`` are the population
+        sizes N_k."""
         raise NotImplementedError
 
     def merge(self, a, b):
@@ -639,9 +655,33 @@ class MomentsAccumulator(Accumulator):
     """Eq 4 sample moments (:class:`StratumStats`), exact Chan merges."""
 
     kind = "moments"
+    sorted_pass = True
 
     def accumulate(self, values, stratum_idx, mask, num_slots, counts=None):
-        return sample_stats(values, stratum_idx, mask, num_slots, counts=counts)
+        return accumulate_sorted(((self, values, mask),), stratum_idx, num_slots, counts)[0]
+
+    def sorted_leaves(self, values, mask):
+        v = jnp.where(mask, values.astype(jnp.float32), 0.0)
+        return _MomentRun(n=mask.astype(jnp.float32), wsum=v, mean=v, m2=jnp.zeros_like(v))
+
+    def sorted_combine(self, a, b):
+        # Chan et al.: the runs' means and centred second moments merge
+        # without cancellation; wsum stays a plain sum of the run's values
+        n = a.n + b.n
+        w = jnp.where(n > 0, b.n / jnp.maximum(n, 1.0), 0.0)
+        d = b.mean - a.mean
+        return _MomentRun(
+            n=n, wsum=a.wsum + b.wsum, mean=a.mean + d * w, m2=a.m2 + b.m2 + d * d * a.n * w
+        )
+
+    def sorted_state(self, runs, present, counts):
+        n = jnp.where(present, runs.n, 0.0)
+        wsum = jnp.where(present, runs.wsum, 0.0)
+        mean = jnp.where(n > 0, wsum / jnp.maximum(n, 1.0), 0.0)
+        return StratumStats(
+            n=n, total=counts.astype(jnp.float32), wsum=wsum,
+            m2=jnp.where(present, runs.m2, 0.0), mean=mean,
+        )
 
     def from_kernel_rows(self, count, s1, s2, counts):
         """Optional kernel hook: adapt fused-kernel raw power-sum rows
@@ -743,12 +783,21 @@ class ExtremaAccumulator(Accumulator):
     """Per-stratum min/max lattices with ±inf identities."""
 
     kind = "extrema"
+    sorted_pass = True
 
     def accumulate(self, values, stratum_idx, mask, num_slots, counts=None):
+        return accumulate_sorted(((self, values, mask),), stratum_idx, num_slots, counts)[0]
+
+    def sorted_leaves(self, values, mask):
         v = values.astype(jnp.float32)
+        return Extrema(min=jnp.where(mask, v, jnp.inf), max=jnp.where(mask, v, -jnp.inf))
+
+    def sorted_combine(self, a, b):
+        return self.merge(a, b)
+
+    def sorted_state(self, runs, present, counts):
         return Extrema(
-            min=jax.ops.segment_min(jnp.where(mask, v, jnp.inf), stratum_idx, num_segments=num_slots),
-            max=jax.ops.segment_max(jnp.where(mask, v, -jnp.inf), stratum_idx, num_segments=num_slots),
+            min=jnp.where(present, runs.min, jnp.inf), max=jnp.where(present, runs.max, -jnp.inf)
         )
 
     def from_kernel_rows(self, mins, maxs) -> Extrema:
@@ -900,6 +949,148 @@ def accumulator(kind: str) -> Accumulator:
     return acc
 
 
+# -- the stratum-sorted pass --------------------------------------------------
+#
+# A scatter of N tuples into S+1 slots is the slowest op a pass runs on the
+# TPU: XLA must assume that indices collide.  The sorted pass reduces the
+# sorted-pass states of one sample mask from ONE sort keyed on (stratum
+# slot, kept first, position), with the columns carried as sort operands
+# (nothing is gathered into sorted order), then ONE segmented scan over the
+# sorted runs, read out at each slot's last kept tuple.  Its cost does not
+# depend on S.  Each slot's kept tuples thus form one contiguous run in
+# their original order, and the scan is translation invariant, so a slot's
+# state depends only on the sequence of its kept values: a pass over the
+# whole pane and one over the raw mode's compacted buffer agree bit for bit.
+
+
+class _MomentRun(NamedTuple):
+    """The moments kind's per-run summary in the sorted pass."""
+
+    n: jnp.ndarray
+    wsum: jnp.ndarray
+    mean: jnp.ndarray
+    m2: jnp.ndarray
+
+
+class SortedSegments(NamedTuple):
+    """Where each stratum slot's tuples lie in a stratum-sorted pass.
+
+    start: (N,) bool — the tuple at this sorted position opens a run (a
+           slot's kept tuples, or its other tuples);
+    last:  (S+1,) int32 — sorted position of each slot's last kept tuple
+           (0 where the slot kept none);
+    kept:  (S+1,) int32 — kept tuples of each slot;
+    size:  (S+1,) int32 — tuples of each slot, kept or not.
+    """
+
+    start: jnp.ndarray
+    last: jnp.ndarray
+    kept: jnp.ndarray
+    size: jnp.ndarray
+
+
+def sort_by_stratum(stratum_idx: jnp.ndarray, mask: jnp.ndarray, num_slots: int, operands):
+    """One sort keyed on ``(slot, not mask, position)`` that carries
+    ``operands`` (``(N,)`` arrays) along; returns ``(SortedSegments, sorted
+    mask, sorted operands)``.
+
+    The position makes every key unique, so the order is the stable one at
+    the price of an unstable sort (which the TPU compiles several times
+    faster), packed into one uint32 key where the three fit in 32 bits.
+    Run boundaries come from one binary search of the sorted runs for every
+    ``(slot, kept)`` key (``log2 N`` gathers of ``2 * (S+1)``).  Slot ids outside
+    ``[0, S+1)`` share one run that no slot reads, as ``jax.ops.segment_*``
+    drops them."""
+    n = stratum_idx.shape[0]
+    s = stratum_idx.astype(jnp.int32)
+    run = jnp.where(
+        (s >= 0) & (s < num_slots), 2 * s + (~mask).astype(jnp.int32), 2 * num_slots
+    )
+    pos_bits = max((n - 1).bit_length(), 1)
+    if pos_bits + (2 * num_slots).bit_length() <= 32:
+        key = (run.astype(jnp.uint32) << pos_bits) | jax.lax.iota(jnp.uint32, n)
+        key, *ops = jax.lax.sort((key, *operands), num_keys=1, is_stable=False)
+        run = (key >> pos_bits).astype(jnp.int32)
+    else:
+        run, _, *ops = jax.lax.sort(
+            (run, jax.lax.iota(jnp.int32, n), *operands), num_keys=2, is_stable=False
+        )
+    start = jnp.concatenate([jnp.ones((1,), bool), run[1:] != run[:-1]])
+    # first position of run q, for q = 2k (slot k's kept tuples), 2k + 1 (its
+    # other tuples) and 2(S+1) (the slot ids outside the table)
+    first = jnp.searchsorted(
+        run, jnp.arange(2 * num_slots + 1, dtype=jnp.int32), side="left",
+        method="scan_unrolled",
+    )
+    begin, kept_end, end = first[0:-1:2], first[1::2], first[2::2]
+    seg = SortedSegments(
+        start=start, last=jnp.maximum(kept_end - 1, 0), kept=kept_end - begin,
+        size=end - begin,
+    )
+    return seg, (run & 1) == 0, tuple(ops)
+
+
+def _segmented_scan(start, runs, combine):
+    """Inclusive segmented scan (Hillis-Steele) along axis 0: afterwards
+    position ``p`` holds the combine of its run's elements up to ``p``.
+    Every step is elementwise over the array shifted by a power of two: no
+    scatter, no gather.  The combine tree at ``p`` depends only on ``p``'s
+    distance from its run's start, so the same run anywhere gives the same
+    bits."""
+    n = start.shape[0]
+    step = 1
+    while step < n:
+        def shifted(x, fill):
+            return jnp.pad(x[:-step], [(step, 0)] + [(0, 0)] * (x.ndim - 1),
+                           constant_values=fill)
+
+        merged = combine(jax.tree.map(lambda x: shifted(x, 0), runs), runs)
+        # ``start`` marks positions whose window already reaches back to
+        # their run's start; position 0 always opens a run
+        runs = jax.tree.map(lambda x, m: jnp.where(start, x, m), runs, merged)
+        start = start | shifted(start, False)
+        step *= 2
+    return runs
+
+
+def accumulate_sorted(
+    requests: Sequence,
+    stratum_idx: jnp.ndarray,
+    num_slots: int,
+    counts: jnp.ndarray | None = None,
+) -> list:
+    """The states of ``requests`` — ``(accumulator, values, mask)`` triples
+    of sorted-pass kinds — from one stratum-sorted pass per distinct mask.
+
+    The requests of one mask share its sort (each distinct values array
+    rides it once as an operand) and one segmented scan.  ``counts`` are the
+    population sizes N_k (the moments' ``total``); when None, each slot's
+    tuples.  Empty and fully masked slots take the identities: 0 for sums,
+    ``±inf`` for extrema."""
+    by_mask: dict = {}
+    for i, (_, _, mask) in enumerate(requests):
+        by_mask.setdefault(id(mask), []).append(i)
+    out: list = [None] * len(requests)
+    for idx in by_mask.values():
+        mask = requests[idx[0]][2]
+        columns = {id(requests[i][1]): requests[i][1] for i in idx}
+        seg, kept, ops = sort_by_stratum(stratum_idx, mask, num_slots, tuple(columns.values()))
+        at = dict(zip(columns, ops))
+        accs = [requests[i][0] for i in idx]
+        leaves = [acc.sorted_leaves(at[id(requests[i][1])], kept) for acc, i in zip(accs, idx)]
+
+        def combine(a, b, accs=accs):
+            return [acc.sorted_combine(x, y) for acc, x, y in zip(accs, a, b)]
+
+        scanned = _segmented_scan(seg.start, leaves, combine)
+        totals = jax.tree.map(lambda x: x[seg.last], scanned)
+        present = seg.kept > 0
+        pop = seg.size if counts is None else counts
+        for acc, i, t in zip(accs, idx, totals):
+            out[i] = acc.sorted_state(t, present, pop)
+    return out
+
+
 # -- column-level operations over {kind: state} dicts ------------------------
 
 
@@ -911,10 +1102,17 @@ def accumulate_column(
     num_slots: int,
     counts: jnp.ndarray | None = None,
 ) -> dict:
-    """One column's registry states for the requested accumulator kinds."""
+    """One column's registry states for the requested accumulator kinds:
+    the sorted-pass kinds from one :func:`accumulate_sorted`, the others
+    through their own ``accumulate``."""
+    accs = [accumulator(k) for k in kinds]
+    in_sort = iter(accumulate_sorted(
+        [(a, values, mask) for a in accs if a.sorted_pass], stratum_idx, num_slots, counts
+    ))
     return {
-        k: accumulator(k).accumulate(values, stratum_idx, mask, num_slots, counts=counts)
-        for k in kinds
+        a.kind: next(in_sort) if a.sorted_pass
+        else a.accumulate(values, stratum_idx, mask, num_slots, counts=counts)
+        for a in accs
     }
 
 

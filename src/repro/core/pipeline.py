@@ -14,8 +14,13 @@ Edge tier  = the mesh shards along the data axes: each shard independently
              moment reductions run on a configurable backend
              (``PipelineConfig.backend``):
 
-               * ``"segment"`` — per-column ``jax.ops.segment_*`` (the
-                 portable path and the parity oracle);
+               * ``"segment"`` — one stratum-sorted pass
+                 (``estimators.accumulate_sorted``): one sort keyed on the
+                 stratum, the sample mask and the position carries every
+                 column, and one segmented scan yields every column's
+                 moment and extrema states without a scatter (the portable
+                 path and the parity oracle; the sketch keeps its own
+                 reduction);
                * ``"pallas"``  — ONE fused multi-column edge-reduce pass
                  (``kernels/edge_reduce``): all fusion-group columns'
                  moment rows contract against the one-hot stratum tile in
@@ -182,6 +187,31 @@ def edge_sample(
     return sidx, SampleResult(mask=mask, weight=weight, n_k=n_k, counts=counts)
 
 
+# registry kinds each backend's kernel emits states for; every other
+# (column, kind) state is reduced through the registry
+_KERNEL_KINDS = {"segment": frozenset(), "pallas": frozenset({"moments"}), "fused": _FUSED_STAT_KINDS}
+
+
+def reduce_routes(plan: Plan, backend: str) -> tuple:
+    """``(column, kind, route)`` for every state a pass reduces for ``plan``:
+    ``"kernel"`` (the backend's kernel emits it), ``"sorted"`` (the pass's
+    stratum-sorted scan, :func:`~.estimators.accumulate_sorted`) or
+    ``"scatter"`` (the kind's own ``accumulate``).  Moments are reduced for
+    every column.  Known from the plan at trace time."""
+    kinds_map = plan.column_kind_map
+    routes = []
+    for c in plan.columns:
+        for kind in dict.fromkeys(("moments",) + tuple(kinds_map[c])):
+            if kind in _KERNEL_KINDS[backend]:
+                route = "kernel"
+            elif estimators.accumulator(kind).sorted_pass:
+                route = "sorted"
+            else:
+                route = "scatter"
+            routes.append((c, kind, route))
+    return tuple(routes)
+
+
 def _accumulate_columns(
     plan: Plan,
     cfg: PipelineConfig,
@@ -196,9 +226,11 @@ def _accumulate_columns(
     The moment states of ALL columns come from one fused multi-column
     edge-reduce pass when ``cfg.backend == "pallas"`` (the MXU kernel on
     TPU, the stacked single-pass segment reduce elsewhere) — one window
-    traversal for the whole fusion group — or from per-column segment ops
-    on the ``"segment"`` oracle backend.  Non-moment kinds (extrema
-    lattices, quantile sketches) accumulate via their registry entries.
+    traversal for the whole fusion group.  The other states follow
+    :func:`reduce_routes`: every sorted-pass state (moments and extrema of
+    every column on the ``"segment"`` backend) comes from ONE
+    stratum-sorted pass, and the other kinds (the sketch) accumulate
+    through their own registry entries.
     """
     with jax.named_scope("reduce"):
         kinds_map = plan.column_kind_map
@@ -234,17 +266,20 @@ def _accumulate_columns(
                 plan.columns, kinds_map, res, 0, res.keep[0], counts,
                 plan.columns, ext_idx, sk_idx,
             )
-        else:
-            for c in plan.columns:
-                stats[c]["moments"] = estimators.MOMENTS.accumulate(
+        in_sort = []
+        for c, kind, route in reduce_routes(plan, cfg.backend):
+            if route == "sorted":
+                in_sort.append((c, kind))
+            elif route == "scatter":
+                stats[c][kind] = estimators.accumulator(kind).accumulate(
                     cols[c], sidx, mask, num_slots, counts=counts
                 )
-        for c in plan.columns:
-            for kind in kinds_map[c]:
-                if kind not in stats[c]:
-                    stats[c][kind] = estimators.accumulator(kind).accumulate(
-                        cols[c], sidx, mask, num_slots, counts=counts
-                    )
+        states = estimators.accumulate_sorted(
+            [(estimators.accumulator(k), cols[c], mask) for c, k in in_sort],
+            sidx, num_slots, counts,
+        )
+        for (c, kind), state in zip(in_sort, states):
+            stats[c][kind] = state
         return stats
 
 

@@ -98,6 +98,7 @@ from . import feedback
 from . import query as aqp
 from . import spans
 from .feedback import SLO, ControllerState
+from .pipeline import reduce_routes
 from .query import (
     FusedPlan,
     Plan,
@@ -733,6 +734,14 @@ class StreamSession:
                 program="refined" if refined else "shared",
             ):
                 lat, lon, cols, valid = self.pipe._window_arrays(pane, fused.shared)
+                # the (column, kind) states this pass reduces, by route
+                routes = [
+                    route
+                    for plan in (fused.members if refined else (fused.shared,))
+                    for _, _, route in reduce_routes(plan, self.pipe.config.backend)
+                ]
+                spans.count("reduce.sorted_states", routes.count("sorted"))
+                spans.count("reduce.scatter_states", routes.count("scatter"))
                 if refined:
                     fn = grp._refined_fn
                     if fn is None:

@@ -213,7 +213,7 @@ def test_compiles_counted_under_emit_until_ring_is_warm(table, panes):
     assert all(e > 0 for e in emit[:4]), emit
     assert all(d.get("compiles.session.finalize", 0) > 0 for d in per_pane[:4]), per_pane
     assert emit[4:] == [0] * (len(panes) - 4), emit
-    assert all(k.startswith(("compiles.", "cache_loads.", "session.", "runtime."))
+    assert all(k.startswith(("compiles.", "cache_loads.", "session.", "runtime.", "reduce."))
                for k in prev)
 
 

@@ -1033,24 +1033,28 @@ def sort_by_stratum(stratum_idx: jnp.ndarray, mask: jnp.ndarray, num_slots: int,
 def _segmented_scan(start, runs, combine):
     """Inclusive segmented scan (Hillis-Steele) along axis 0: afterwards
     position ``p`` holds the combine of its run's elements up to ``p``.
-    Every step is elementwise over the array shifted by a power of two: no
-    scatter, no gather.  The combine tree at ``p`` depends only on ``p``'s
+    Step ``i`` combines every position with the one ``2**i`` before it,
+    elementwise: no scatter, no gather; the steps run as a loop, so the
+    program holds one.  The combine tree at ``p`` depends only on ``p``'s
     distance from its run's start, so the same run anywhere gives the same
     bits."""
     n = start.shape[0]
-    step = 1
-    while step < n:
-        def shifted(x, fill):
-            return jnp.pad(x[:-step], [(step, 0)] + [(0, 0)] * (x.ndim - 1),
-                           constant_values=fill)
 
-        merged = combine(jax.tree.map(lambda x: shifted(x, 0), runs), runs)
+    def shifted(x, by, fill):
+        return jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([jnp.full_like(x, fill), x]), n - by, n
+        )
+
+    def step(i, carry):
+        start, runs = carry
+        by = jnp.left_shift(1, i)
+        merged = combine(jax.tree.map(lambda x: shifted(x, by, 0), runs), runs)
         # ``start`` marks positions whose window already reaches back to
         # their run's start; position 0 always opens a run
         runs = jax.tree.map(lambda x, m: jnp.where(start, x, m), runs, merged)
-        start = start | shifted(start, False)
-        step *= 2
-    return runs
+        return start | shifted(start, by, False), runs
+
+    return jax.lax.fori_loop(0, (n - 1).bit_length(), step, (start, runs))[1]
 
 
 def accumulate_sorted(

@@ -66,6 +66,7 @@ from typing import Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from . import codec as wirecodec
@@ -791,6 +792,12 @@ class EdgeCloudPipeline:
             self._plans[query] = p
         return p
 
+    @property
+    def input_sharding(self) -> NamedSharding:
+        """Where the sharded programs take a pane's columns: split over the
+        data axes, each edge node's contiguous share on its own device."""
+        return NamedSharding(self.mesh, P(self.axis_names))
+
     def _compiled(self, plan: Plan, body, out_template, sharded: bool):
         """Jit ``body(key, lat, lon, cols, valid, fraction, axes=None)`` —
         directly, or wrapped in shard_map over the data axes (shards = edge
@@ -800,7 +807,7 @@ class EdgeCloudPipeline:
         if not sharded:
             return jax.jit(body)
         axes = self.axis_names
-        spec = P(axes)
+        spec = self.input_sharding.spec
         mapped = jax.shard_map(
             partial(body, axes=axes),
             mesh=self.mesh,

@@ -264,6 +264,8 @@ class StreamRuntime:
         self._clock = self.config.clock
         self._recorder = spans.Recorder(self._clock)
         self._root_key = key
+        # where _stage puts a pane: None (one edge node) or the session's split
+        self._sharding = getattr(session, "input_sharding", None)
         self._history: list = []
         self._timings: list[PaneTiming] = []
         self._inflight: collections.deque[_InFlight] = collections.deque()
@@ -462,17 +464,25 @@ class StreamRuntime:
             self.flush()
 
     def _stage(self, arrival: _Arrival, t_dequeue: float) -> _Staged:
-        """Issue the pane's host→device transfers (async on real backends)."""
+        """Issue the pane's host→device transfers (async on real backends).
+
+        A sharded session's pane is put with its input sharding: each edge
+        node's contiguous share goes to that node's device and to no other,
+        so no program has to reshard it.  A pane whose length the node count
+        does not divide fails here (``jax.device_put`` raises)."""
         pane = arrival.pane
+        sharding = self._sharding  # None: the default device, as for one node
         with spans.span("runtime.stage", pane=self._next_pane_index()) as sp:
             staged = dataclasses.replace(
                 pane,
-                lat=jax.device_put(pane.lat),
-                lon=jax.device_put(pane.lon),
-                value=jax.device_put(pane.value),
-                valid=jax.device_put(pane.valid),
-                extra={k: jax.device_put(v) for k, v in pane.extra.items()},
+                lat=jax.device_put(pane.lat, sharding),
+                lon=jax.device_put(pane.lon, sharding),
+                value=jax.device_put(pane.value, sharding),
+                valid=jax.device_put(pane.valid, sharding),
+                extra={k: jax.device_put(v, sharding) for k, v in pane.extra.items()},
             )
+            if sharding is not None:
+                spans.count("runtime.sharded_stages")
         return _Staged(arrival, staged, t_dequeue, sp.t1)
 
     def _dispatch(self, staged: _Staged) -> None:

@@ -493,6 +493,13 @@ class StreamSession:
 
     # -- fusion machinery ----------------------------------------------------
 
+    @property
+    def input_sharding(self):
+        """Where a pane's columns go: the pipeline's input sharding on a
+        sharded session (each edge node's share on its own device), None
+        for one node."""
+        return self.pipe.input_sharding if self.sharded else None
+
     def _groups(self) -> list[list[Registration]]:
         """The fusion partition as member lists (compatibility view over the
         incremental group structure): registration order within groups,
@@ -797,6 +804,9 @@ class StreamSession:
                             (carved, n_sampled, n_valid, n_overflow, n_truncated, comm)
                         )
                 comm_total += comm
+                spans.count(
+                    "uplink.raw_bytes" if fused.mode == "raw" else "uplink.preagg_bytes", comm
+                )
                 self.total_passes += 1
                 for reg, (stats_m, n_s, n_v, n_o, n_t, comm_m) in zip(members, per_member):
                     reg.ring.append(

@@ -38,6 +38,7 @@ from repro.core import (
     spans,
     windows,
 )
+from repro.core import query as aqp
 from repro.data.streams import shenzhen_taxi_stream
 
 PANE = 500
@@ -213,8 +214,26 @@ def test_compiles_counted_under_emit_until_ring_is_warm(table, panes):
     assert all(e > 0 for e in emit[:4]), emit
     assert all(d.get("compiles.session.finalize", 0) > 0 for d in per_pane[:4]), per_pane
     assert emit[4:] == [0] * (len(panes) - 4), emit
-    assert all(k.startswith(("compiles.", "cache_loads.", "session.", "runtime.", "reduce."))
+    assert all(k.startswith(("compiles.", "cache_loads.", "session.", "runtime.", "reduce.",
+                             "uplink."))
                for k in prev)
+
+
+def test_one_node_stages_unsharded_and_counts_its_uplink_bytes(table, panes):
+    """A session of one edge node stages no pane with a sharding, and the
+    uplink counters add the per-shard bytes of every pass: the sliding
+    query's shared pass and the pair's refined one, both preagg."""
+    sess, _ = _session(EdgeCloudPipeline(table, PipelineConfig()))
+    assert sess.input_sharding is None
+    rt = StreamRuntime(sess, key=jax.random.key(5), config=RuntimeConfig(policy="block"))
+    history = rt.run(panes)
+    c = rt.stats().counters
+    assert "runtime.sharded_stages" not in c and "uplink.raw_bytes" not in c
+    slide, pair = (g.fused_plan() for g in sess._fusion_groups.values())
+    per_pane = (aqp.preagg_bytes(slide.shared, table.num_slots)
+                + aqp.refined_preagg_bytes(pair, table.num_slots))
+    assert c["uplink.preagg_bytes"] == len(panes) * per_pane
+    assert c["uplink.preagg_bytes"] == sum(step.comm_bytes for step in history)
 
 
 def test_stack_and_finalize_spans_nest_inside_emit(table, panes):
@@ -380,6 +399,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from repro.core import (SHENZHEN_BBOX, AggSpec, EdgeCloudPipeline, PipelineConfig, Query,
                         make_table, windows)
+from repro.core import query as aqp
 from repro.data.streams import shenzhen_taxi_stream
 from repro.sharding.compat import compat_make_mesh
 

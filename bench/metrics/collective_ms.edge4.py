@@ -1,8 +1,8 @@
-"""Device milliseconds per pane of the uplink's collectives (the preagg
-psum and the raw buffer's all_gather, which the TPU compiler emits as
-``all-reduce`` ops), mean over the chips, from the profiler trace."""
+"""Device milliseconds per pane of the uplink's collectives (``all-reduce``
+from the preagg psum, ``all-gather`` from the raw buffer), mean over the
+chips, from the profiler trace."""
 
 
 def read(window):
     t = window.trace
-    return t.collective_ms_per_pane() if t is not None else None
+    return t.op_ms_per_pane("all-reduce", "all-gather") if t is not None else None

@@ -1,6 +1,5 @@
 """The four-edge-node cell on four virtual CPU devices: a sound run comes out
-correct, and one whose uplink exchange is left out does not, whether the
-missing exchange is the preagg psum or the raw buffer's all_gather."""
+correct, and one whose uplink exchange is left out does not."""
 
 from __future__ import annotations
 
@@ -10,52 +9,35 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
-
 ROOT = Path(__file__).resolve().parents[2]
 
 SCRIPT = r"""
 import json, sys, time
-from pathlib import Path
 sys.path[:0] = [sys.argv[1] + "/src", sys.argv[1]]
-import jax
 from bench import cell, reference
 from bench.tests.conftest import cell_of
 from repro.core import estimators
 
-uplink_mb = cell.load_module(Path(sys.argv[1]) / "bench/metrics/uplink_mb.edge4.py").read
-psum_accs, all_gather = estimators.psum_accs, jax.lax.all_gather
 out = {}
-for name in ("sound", "no_psum", "no_gather"):
-    # each edge node keeps its own per-stratum states: no psum
-    estimators.psum_accs = ((lambda stats, axes, shared=None: stats) if name == "no_psum"
-                            else psum_accs)
-    # each edge node consolidates only its own sampled tuples: no all_gather
-    jax.lax.all_gather = (lambda x, axes, **kw: x) if name == "no_gather" else all_gather
+for name in ("sound", "no_exchange"):
+    if name == "no_exchange":
+        # each edge node keeps its own per-stratum states: no psum
+        estimators.psum_accs = lambda stats, axes, shared=None: stats
     ctx = cell_of("taxi_gh6_edge4", "edge4_backlog")
-    window, checks = cell.run_window(ctx, 4000000041, 1.0, t_process=time.perf_counter(),
-                                     log=lambda *a, **k: None)
-    out[name] = {"checks": {k: [v, ok] for k, (v, _, ok) in reference.verdicts(checks).items()},
-                 "uplink_mb": uplink_mb(window)}
+    _, checks = cell.run_window(ctx, 4000000041, 1.0, t_process=time.perf_counter(),
+                                log=lambda *a, **k: None)
+    out[name] = {k: [v, ok] for k, (v, _, ok) in reference.verdicts(checks).items()}
 print(json.dumps(out))
 """
 
 
-@pytest.fixture(scope="module")
-def runs():
+def test_edge_nodes_need_their_exchange():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     proc = subprocess.run([sys.executable, "-c", SCRIPT, str(ROOT)], env=env,
-                          capture_output=True, text=True, timeout=900)
+                          capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
-@pytest.mark.parametrize("fault", ["no_psum", "no_gather"])
-def test_edge_nodes_need_their_exchange(runs, fault):
-    sound = runs["sound"]["checks"]
-    assert all(ok for _, ok in sound.values()), sound
-    assert sound["steps_checked"][0] >= 1
-    assert runs["sound"]["uplink_mb"] > 0.0
-    faulty = runs[fault]["checks"]
-    assert not all(ok for _, ok in faulty.values()), faulty
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert all(ok for _, ok in out["sound"].values()), out["sound"]
+    assert out["sound"]["steps_checked"][0] >= 1
+    assert not all(ok for _, ok in out["no_exchange"].values()), out["no_exchange"]

@@ -49,18 +49,3 @@ def test_program_spans_avoid_the_harness_span_names():
         program |= set(re.findall(r'spans\.span\(\s*"([^"]+)"', path.read_text()))
     assert {"runtime.dispatch", "session.pass", "session.emit"} <= program
     assert not program & harness
-
-
-def test_uplink_reader_reads_the_uplink_counters():
-    """``uplink_mb.edge4``: both uplink counters over the window's panes, in
-    10^6 B; nothing where the program keeps neither counter."""
-    read = _reader("uplink_mb.edge4")
-    stats = types.SimpleNamespace(panes_processed=4, counters={
-        "uplink.preagg_bytes": 1_259_136, "uplink.raw_bytes": 14_400_000,
-        "runtime.sharded_stages": 4})
-    assert read(types.SimpleNamespace(runtime_stats=stats)) == (1_259_136 + 14_400_000) / 4e6
-    preagg_only = types.SimpleNamespace(panes_processed=2,
-                                        counters={"uplink.preagg_bytes": 1_000_000})
-    assert read(types.SimpleNamespace(runtime_stats=preagg_only)) == 0.5
-    bare = types.SimpleNamespace(runtime_stats=types.SimpleNamespace(panes_processed=3))
-    assert read(bare) is None

@@ -95,7 +95,6 @@ def test_reduction_of_a_recorded_chip_trace():
     assert r.panes == 2
     assert r.op_ms_per_pane("sort") == pytest.approx(1.599602 / 2, rel=1e-6)
     assert r.op_ms_per_pane("all-reduce", "all-gather") is None
-    assert r.collective_ms_per_pane() is None  # one chip: no collective ran
     bd = r.breakdown()
     assert bd["device_ops"][0][0].startswith("while.4 (s32[], s32[200000], s32[200000], u32[200000]")
     assert bd["device_ops"][0][1] == pytest.approx(0.049314358, rel=1e-6)
@@ -103,26 +102,3 @@ def test_reduction_of_a_recorded_chip_trace():
                                   pytest.approx(0.011022234, rel=1e-6)]
     assert dict(bd["idle_gaps"])["session.step"] == pytest.approx(
         0.004696388, rel=1e-6)
-
-
-def test_collectives_count_once_each():
-    """``collective_ms.edge4`` takes a synchronous collective once and an
-    asynchronous one once, by its ``-done`` half; a fusion that merely
-    starts with a collective's name, and every other op, are left out."""
-    from bench import cell
-
-    read = cell.load_module(ROOT / "bench/metrics/collective_ms.edge4.py").read
-    r = trace.Reduced(window_s=1.0, busy_s=0.5, panes=2, gaps={}, op_s={
-        "all-reduce.14 u32[1564,1,128]": 0.004,  # the raw buffer's all_gather, lowered
-        "all-reduce.16 (s32[800000], s32[6558])": 0.002,  # combined psums
-        "all-reduce-start.3 f32[6558]": 0.001,
-        "all-reduce-done.3 f32[6558]": 0.003,
-        "all-gather-done f32[800000]": 0.002,
-        "all-reduce-scatter-fusion.1 f32[6558]": 0.5,
-        "sort.65 (s32[800000], s32[800000], f32[800000])": 0.25,
-    })
-    assert r.collective_ms_per_pane() == pytest.approx((0.004 + 0.002 + 0.003 + 0.002) * 1e3 / 2)
-    assert read(type("W", (), {"trace": r})()) == r.collective_ms_per_pane()
-    assert read(type("W", (), {"trace": None})()) is None
-    quiet = trace.Reduced(window_s=1.0, busy_s=0.5, panes=2, gaps={}, op_s={"sort.1 s32[8]": 0.1})
-    assert quiet.collective_ms_per_pane() is None

@@ -21,12 +21,6 @@ import numpy as np
 
 OPS_LINE = "XLA Ops"
 _LAYOUT = re.compile(r"\{[^{}]*\}|/\*[^*]*\*/")
-# a cross-chip collective, by its instruction name: a synchronous op
-# (``all-reduce.14``) or the ``-done`` half of an asynchronous pair, which
-# holds the wait for the transfer (its ``-start`` half only issues it)
-_COLLECTIVE = re.compile(
-    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)(-done)?(\.\d+)?( |$)"
-)
 
 
 def op_name(hlo: str) -> str:
@@ -80,17 +74,7 @@ class Reduced:
         """Device ms per pane of the ops whose instruction name starts with
         one of ``kinds`` (``sort`` takes ``sort.1``, not a fusion fed by
         it); None where no such op ran."""
-        return self._ms_per_pane(lambda name: name.startswith(kinds))
-
-    def collective_ms_per_pane(self) -> float | None:
-        """Device ms per pane of the cross-chip collectives, each op once
-        (see ``_COLLECTIVE``; the TPU compiler lowers an ``all_gather`` to
-        an ``all-reduce`` and combines a program's psums into one); None
-        where no collective ran."""
-        return self._ms_per_pane(_COLLECTIVE.match)
-
-    def _ms_per_pane(self, match) -> float | None:
-        total = sum(s for name, s in self.op_s.items() if match(name))
+        total = sum(s for name, s in self.op_s.items() if name.startswith(kinds))
         if total <= 0.0 or self.panes == 0:
             return None
         return total * 1e3 / self.panes

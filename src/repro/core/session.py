@@ -30,7 +30,9 @@ approximate analytics wins by sharing one sampling pass):
     extrema exactly; see ``EdgeCloudPipeline.batched_finalize_fn``).
     ``step.results`` materializes per-query views lazily on access, so a
     pane serving thousands of registered dashboards pays O(signatures)
-    dispatches, and only the results actually read pay slicing.
+    dispatches, and each result actually read pays one more: a compiled
+    carve program that takes its row of every stacked leaf (the row is a
+    traced scalar, so one program serves every row of a batch shape).
   * Sliding/hopping windows fall out of the mergeable-accumulator design:
     the edge reduces each *pane* (stride-sized sub-window) to per-stratum
     registry pytrees (``{column: {kind: state}}`` — moments, extrema,
@@ -92,6 +94,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from . import codec as wirecodec
 from . import feedback
@@ -241,13 +244,23 @@ class _EmitBatch(NamedTuple):
 _PENDING = object()
 
 
+@jax.jit
+def _carve_row(stacked, row):
+    """Row ``row`` of every leaf of a batch's stacked outputs.  ``row`` is
+    traced, so one compiled program per batch shape serves every row; a
+    slice is exact, and the leaves keep their sharding."""
+    return jax.tree.map(
+        lambda x: lax.dynamic_index_in_dim(x, row, keepdims=False), stacked
+    )
+
+
 def _carve_result(batch: _EmitBatch, i: int) -> QueryResult:
-    """Materialize member ``i``'s :class:`QueryResult` view of a batch."""
-    estimates = {
-        k: aqp.AggEstimate(*(x[i] for x in est))
-        for k, est in batch.estimates.items()
-    }
-    stats = jax.tree.map(lambda x: x[i], batch.stats)
+    """Materialize member ``i``'s :class:`QueryResult` view of a batch: one
+    dispatch of :func:`_carve_row` for its estimates and stats; the window
+    counters are per member already."""
+    with spans.span("session.carve", row=i):
+        estimates, stats = _carve_row((batch.estimates, batch.stats), i)
+    spans.count("session.carved_results")
     n_s, n_v, n_o, n_t, comm, dropped = batch.counters[i]
     return QueryResult(
         estimates=estimates,
@@ -266,11 +279,12 @@ def _carve_result(batch: _EmitBatch, i: int) -> QueryResult:
 class _LazyResults(dict):
     """``qid -> QueryResult`` mapping over batched finalize output.
 
-    Batch members materialize (slice their rows out of the stacked
-    estimates/stats) only on access — iteration, ``values()``, ``items()``,
-    ``get`` and ``[]`` all materialize; membership/len/``keys()`` never do.
-    A pane that served thousands of registrations therefore pays per-query
-    slicing only for the results something actually reads.
+    Batch members materialize (carve their row out of the stacked
+    estimates/stats, :func:`_carve_result`) only on access, once each —
+    iteration, ``values()``, ``items()``, ``get`` and ``[]`` all
+    materialize; membership/len/``keys()`` never do.  A pane that served
+    thousands of registrations therefore pays one carve dispatch per result
+    something actually reads, and none for the rest.
     """
 
     def __init__(self):

@@ -174,10 +174,12 @@ def test_runtime_records_spans_and_counters_per_pane(table, panes):
         "session.emit": n,
         "session.stack": n - 1,  # the sliding ring, from its second pane on
         "session.finalize": 2 * n,
+        "session.carve": 2 * n,  # the pair's views, read by the retire markers
         "runtime.retire": n,
     }
     # the sliding query's finalize and one batched finalize for the pair
     assert c["session.finalize_dispatches"] == 2 * n
+    assert c["session.carved_results"] == 2 * n
     # from its second pane on the ring goes to the finalize program unstacked,
     # and the program stacks it, leaf by leaf
     assert c["session.program_stacked_windows"] == counts["session.stack"] == n - 1
@@ -189,7 +191,7 @@ def test_runtime_records_spans_and_counters_per_pane(table, panes):
     for s in st.spans.values():
         assert 0.0 <= s["self_s"] <= s["total_s"]
     d = st.spans["runtime.dispatch"]
-    inner = st.spans["session.pass"]["total_s"] + st.spans["session.emit"]["total_s"]
+    inner = sum(st.spans[k]["total_s"] for k in ("session.pass", "session.emit", "session.carve"))
     assert d["self_s"] == pytest.approx(d["total_s"] - inner)
     # PaneTiming reads the spans' own clock readings
     assert sum(t.dispatch_s for t in rt._timings) == pytest.approx(d["total_s"])
